@@ -53,6 +53,9 @@ def main(argv=None):
                     help="suite scale factor (default 1; pallas interpret "
                          "uses smaller shapes unless overridden)")
     args = ap.parse_args(argv)
+    from repro.runtime.cache import enable_compilation_cache
+
+    enable_compilation_cache()
     pallas = args.impl == "pallas"
     iters = args.iters if args.iters is not None else (3 if pallas else 20)
     specs = paper_small_suite(args.scale or 1)

@@ -295,6 +295,9 @@ def main() -> None:
                     help="also write the CSV rows as machine-readable JSON "
                          "(the CI perf artifact)")
     args = ap.parse_args()
+    from repro.runtime.cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     if args.tune:
         _tune_block(args.smoke, args.json or "BENCH_autotune.json")

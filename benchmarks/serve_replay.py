@@ -334,7 +334,22 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=21)
     args = ap.parse_args(argv)
 
+    if args.workers:
+        # the in-process replays below take the chip, and a worker spawned
+        # after them could not open it: refuse before touching a device
+        from repro.cluster.worker import worker_platform
+
+        platform, _ = worker_platform()
+        if platform != "cpu":
+            print(f"FAIL: --workers needs one chip per worker; on {platform} "
+                  "this process holds the chip its workers would need",
+                  file=sys.stderr)
+            return 2
+
+    from repro.runtime.cache import enable_compilation_cache
     from repro.serve import WorkloadSpec, generate_trace, replay
+
+    enable_compilation_cache()
 
     n = args.requests if args.requests is not None else (48 if args.smoke
                                                          else 160)

@@ -61,6 +61,9 @@ def main(argv=None) -> int:
                     help="fail below this iterate-vs-host-loop ratio")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    from repro.runtime.cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     from repro.data.matrices import scale_free_matrix
     from repro.engine import SpmvEngine

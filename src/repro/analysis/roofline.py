@@ -1,10 +1,11 @@
 """Roofline analysis from compiled dry-run artifacts (TPU v5e target).
 
-Per (arch x shape x mesh) cell, three terms (system prompt §ROOFLINE):
+Per (arch x shape x mesh) cell, three terms, over the peaks of the
+target chip's ``device_kind`` (:data:`PEAKS`):
 
-    compute_s    = HLO_FLOPs_per_chip    / 197e12      (bf16 MXU peak)
-    memory_s     = HLO_bytes_per_chip    / 819e9       (HBM bandwidth)
-    collective_s = coll_bytes_per_chip   / 50e9        (per-link ICI)
+    compute_s    = HLO_FLOPs_per_chip    / peak bf16 FLOP/s
+    memory_s     = HLO_bytes_per_chip    / HBM bytes/s
+    collective_s = coll_bytes_per_chip   / ICI bytes/s per link
 
 Sources and the scan-undercount correction:
   * XLA counts a `while` (scan) body ONCE in cost_analysis.  We therefore
@@ -28,23 +29,38 @@ from __future__ import annotations
 import re
 from dataclasses import dataclass, field
 
-
-from repro import compat
 from repro.configs.base import SHAPES, ArchConfig
 
 __all__ = [
-    "PEAK_FLOPS",
-    "HBM_BW",
-    "LINK_BW",
+    "PEAKS",
+    "peaks",
     "collective_bytes",
     "CostTerms",
     "roofline_report",
     "model_flops",
 ]
 
-PEAK_FLOPS = 197e12  # bf16 per chip (TPU v5e-class target)
-HBM_BW = 819e9  # bytes/s per chip
-LINK_BW = 50e9  # bytes/s per ICI link
+#: Published per-chip peaks by ``jax.Device.device_kind``.  TPU v5e (Google
+#: Cloud documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s HBM, and
+#: 1,600 Gbit/s of ICI over four links, i.e. 50 GB/s per link.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak ``flops`` / ``hbm_bw`` / ``link_bw`` of one chip of that kind.
+
+    Raises:
+      KeyError: for a device kind without published peaks in :data:`PEAKS`.
+    """
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -93,7 +109,7 @@ class CostTerms:
 
     @classmethod
     def from_compiled(cls, compiled) -> "CostTerms":
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         coll = collective_bytes(compiled.as_text())
         return cls(
             flops=float(ca.get("flops", 0.0)),
@@ -259,10 +275,12 @@ def gla_scan_correction(cfg: ArchConfig, shape_name: str) -> float:
 
 
 def roofline_report(cfg: ArchConfig, shape_name: str, chips: int,
-                    total: CostTerms) -> dict:
-    compute_s = total.flops / PEAK_FLOPS
-    memory_s = total.bytes_hbm / HBM_BW
-    coll_s = total.coll_bytes / LINK_BW
+                    total: CostTerms, device_kind: str) -> dict:
+    """Roofline terms of one cell on ``chips`` chips of ``device_kind``."""
+    peak = peaks(device_kind)
+    compute_s = total.flops / peak["flops"]
+    memory_s = total.bytes_hbm / peak["hbm_bw"]
+    coll_s = total.coll_bytes / peak["link_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": coll_s}
     dominant = max(terms, key=terms.get)
@@ -280,7 +298,7 @@ def roofline_report(cfg: ArchConfig, shape_name: str, chips: int,
         "useful_ratio": mf / hlo_total_flops if hlo_total_flops else 0.0,
         # fraction of roofline: useful work per sec achievable / peak
         "roofline_fraction": (
-            (mf / chips / PEAK_FLOPS) / bound if bound > 0 else 0.0
+            (mf / chips / peak["flops"]) / bound if bound > 0 else 0.0
         ),
         "per_device": {
             "flops": total.flops,

@@ -23,8 +23,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 
-from repro.compat import NamedSharding
 from repro.core import distributed as D
 from repro.core.partition import PartitionedMatrix
 from repro.kernels import ops
@@ -129,12 +129,11 @@ class SingleDeviceExecutor(Executor):
     lane-tiled multi-RHS grid, never a per-column loop.
     """
 
-    def __init__(self, plan, container, impl: str, interpret: bool = True):
+    def __init__(self, plan, container, impl: str):
         self.plan = plan
         self.container = container
         self.impl = impl
-        self.interpret = interpret
-        self._pallas = (ops.pallas_program(container, interpret=interpret)
+        self._pallas = (ops.pallas_program(container)
                         if impl == "pallas" else None)
 
     def __call__(self, x) -> np.ndarray:
@@ -152,8 +151,7 @@ class SingleDeviceExecutor(Executor):
             return self.batch(x)
         if self._pallas is not None:
             return np.asarray(self._pallas(jnp.asarray(x)))
-        y = ops.spmv(self.container, jnp.asarray(x), impl=self.impl,
-                     interpret=self.interpret)
+        y = ops.spmv(self.container, jnp.asarray(x), impl=self.impl)
         return np.asarray(y)
 
     def batch(self, X) -> np.ndarray:
@@ -190,8 +188,7 @@ class SingleDeviceExecutor(Executor):
             return apply
 
         def apply(v):
-            y = ops.spmv(self.container, v, impl=self.impl,
-                         interpret=self.interpret)
+            y = ops.spmv(self.container, v, impl=self.impl)
             return y.astype(dtype) if y.dtype != dtype else y
         return apply
 

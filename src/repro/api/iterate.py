@@ -5,7 +5,7 @@ Jacobi/Richardson sweeps) where the vector feeds straight back into the next
 multiply.  ``Executor.__call__`` round-trips y through the host every step;
 :func:`run_iterate` instead compiles the *whole* solver loop — k SpMVs plus
 the per-step combine — into one ``lax.scan`` / ``lax.while_loop`` program
-(through :mod:`repro.compat`, carry buffers donated), so x never leaves the
+(carry buffers donated), so x never leaves the
 device between steps.  This is the ALPHA-PIM extension of SparseP
 (arXiv:2602.09174): the same PIM kernels, re-driven as solver sessions.
 
@@ -35,15 +35,20 @@ import time
 from dataclasses import dataclass
 from typing import Callable, Optional, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
-
-from repro import compat
 
 __all__ = ["IterateResult", "Combine", "make_combine", "run_iterate",
            "COMBINES"]
 
 _TINY = 1e-30  # normalization floor: keeps power iteration NaN-free on y=0
+
+
+def _dot(a, b):
+    """Inner product at full precision: at default precision the TPU
+    multiplies f32 in one bf16 pass."""
+    return jnp.vdot(a, b, precision=jax.lax.Precision.HIGHEST)
 
 
 @dataclass(frozen=True)
@@ -159,7 +164,7 @@ class CGCombine(Combine):
 
     def init(self, x0, params, apply):
         r = params["b"] - apply(x0)
-        rs = jnp.vdot(r, r).real.astype(x0.dtype)
+        rs = _dot(r, r).real.astype(x0.dtype)
         return {"x": x0, "r": r, "p": r, "rs": rs,
                 "res": jnp.sqrt(rs)}
 
@@ -168,11 +173,11 @@ class CGCombine(Combine):
 
     def step(self, carry, y, params):
         x, r, p, rs = carry["x"], carry["r"], carry["p"], carry["rs"]
-        denom = jnp.vdot(p, y).real.astype(rs.dtype)
+        denom = _dot(p, y).real.astype(rs.dtype)
         alpha = rs / jnp.where(denom == 0, jnp.asarray(_TINY, rs.dtype), denom)
         x_new = x + alpha * p
         r_new = r - alpha * y
-        rs_new = jnp.vdot(r_new, r_new).real.astype(rs.dtype)
+        rs_new = _dot(r_new, r_new).real.astype(rs.dtype)
         beta = rs_new / jnp.where(rs == 0, jnp.asarray(_TINY, rs.dtype), rs)
         p_new = r_new + beta * p
         return {"x": x_new, "r": r_new, "p": p_new, "rs": rs_new,
@@ -358,10 +363,10 @@ def _build_loop(comb: Combine, apply: Callable, steps: Optional[int],
             def body(carry, _):
                 return one_step(carry, params), None
 
-            carry, _ = compat.scan(body, carry0, length=steps)
+            carry, _ = jax.lax.scan(body, carry0, length=steps)
             return carry, jnp.asarray(steps, jnp.int32)
 
-        return compat.jit_donated(loop_steps, donate_argnums=(0,))
+        return jax.jit(loop_steps, donate_argnums=(0,))
 
     def loop_tol(x0_dev, params):
         carry0 = comb.init(x0_dev, params, apply)
@@ -381,9 +386,9 @@ def _build_loop(comb: Combine, apply: Callable, steps: Optional[int],
             def inner(_, c):
                 return one_step(c, params)
 
-            carry = compat.fori_loop(0, n_inner, inner, carry)
+            carry = jax.lax.fori_loop(0, n_inner, inner, carry)
             return carry, k + n_inner
 
-        return compat.while_loop(cond, body, state0)
+        return jax.lax.while_loop(cond, body, state0)
 
-    return compat.jit_donated(loop_tol, donate_argnums=(0,))
+    return jax.jit(loop_tol, donate_argnums=(0,))

@@ -26,6 +26,7 @@ from repro import compat
 from repro.core import formats as F
 from repro.core.adaptive import HardwareModel, Plan, estimate_time
 from repro.core.stats import MatrixStats, compute_stats
+from repro.kernels.mode import interpret_mode
 
 from .executor import AXES_2D, AXIS_1D
 from .plan import ExecutionPlan, resolve_scheme
@@ -266,7 +267,6 @@ class SparseMatrix:
         merge: Optional[str] = None,
         grid: Optional[tuple] = None,
         block: Tuple[int, int] = (8, 16),
-        interpret: bool = True,
         fit: bool = True,
         tuner=None,
         tune_cache=None,
@@ -282,7 +282,8 @@ class SparseMatrix:
             empirically fastest), a string like "1d.nnz" /
             "2d.equally-sized", or an explicit adaptive.Plan.
           impl: "xla" (the jnp oracles; lower on every backend) or "pallas"
-            (the TPU kernels; ``interpret=True`` validates them on CPU).
+            (the TPU kernels: compiled on TPU, interpreted on CPU).  On TPU
+            only the block formats compile, so ``"auto"`` picks one there.
             Both compose with ``mesh=``/``devices=``: distributed plans run
             the chosen impl as the per-shard tile kernel inside shard_map.
           hw: HardwareModel driving the analytic scheme selection/estimates.
@@ -291,7 +292,6 @@ class SparseMatrix:
           partitioning: force "1d"/"2d" over the adaptive choice.
           fmt/merge/grid: override single dimensions of the resolved scheme.
           block: (r, c) tile for the block formats and the stats blocking.
-          interpret: Pallas interpret mode (keep True off-TPU).
           fit: False inspects the paper plan for ``hw`` as-is, without
             fitting its grid to this pool (not compilable unless the pool
             happens to match).
@@ -324,8 +324,9 @@ class SparseMatrix:
           winner-vs-analytic numbers.
 
         Raises:
-          ValueError: unknown impl/scheme, both mesh= and devices= given, or
-            a user mesh whose shape the fitted plan cannot lay out on.
+          ValueError: unknown impl/scheme, both mesh= and devices= given,
+            a user mesh whose shape the fitted plan cannot lay out on, or
+            impl="pallas" with a scalar format (coo/csr) on TPU.
         """
         if impl not in ("xla", "pallas"):
             raise ValueError(f"unknown impl {impl!r}: 'xla' or 'pallas'")
@@ -363,7 +364,7 @@ class SparseMatrix:
                 )
             return tuner.tune(
                 self, devices=devices, mesh=mesh, block=block, hw=hw,
-                interpret=interpret, batch=batch, topology=topology,
+                batch=batch, topology=topology,
             ).best
         distributed = mesh is not None or devices is not None
         if mesh is not None:
@@ -377,11 +378,13 @@ class SparseMatrix:
             n_devices = len(devices)
         else:
             n_devices = 1
+        compiled_pallas = impl == "pallas" and not interpret_mode(
+            mesh.devices.flat if mesh is not None else devices)
         plan = resolve_scheme(
             self.stats, self.shape, n_devices, scheme, hw=hw,
             partitioning=partitioning, fmt=fmt, merge=merge, grid=grid,
             block=block, fit=fit, topology=topology,
-            dtype_bytes=self.dtype.itemsize,
+            dtype_bytes=self.dtype.itemsize, compiled_pallas=compiled_pallas,
         )
         if mesh is not None:
             # fail fast: the fitted plan must lay out on the given mesh, or
@@ -441,7 +444,7 @@ class SparseMatrix:
         return ExecutionPlan(
             matrix=self, scheme=plan, impl=impl,
             mesh=mesh if distributed else None, dtype=self.dtype,
-            block=tuple(block), interpret=interpret, hw=hw, estimate=est,
+            block=tuple(block), hw=hw, estimate=est,
             topo_assignment=topo_assignment,
         )
 

@@ -4,7 +4,7 @@
 :class:`repro.core.adaptive.Plan`: partitioning, balancing scheme, format,
 merge collective, grid), fits it to the actual device pool, and returns an
 :class:`ExecutionPlan` that additionally pins *how to run it* (impl, mesh,
-dtype, interpret) plus the analytic time estimate.  ``.compile()`` turns it
+dtype) plus the analytic time estimate.  ``.compile()`` turns it
 into an :class:`repro.api.executor.Executor`.
 
 This subsumes the two plan notions that predate it: ``adaptive.Plan`` (the
@@ -20,8 +20,8 @@ from dataclasses import dataclass, field, replace
 from typing import Optional, Tuple
 
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
-from repro.compat import P
 from repro.core import distributed as D
 from repro.core.adaptive import HardwareModel, Plan, select_scheme
 from repro.core.partition import (
@@ -32,6 +32,7 @@ from repro.core.partition import (
     partition_2d,
 )
 from repro.core.stats import MatrixStats
+from repro.kernels.mode import interpret_mode
 
 from .executor import AXES_2D, AXIS_1D, Executor, MeshExecutor, SingleDeviceExecutor
 
@@ -45,6 +46,7 @@ __all__ = [
 ]
 
 FORMATS = ("coo", "csr", "bcoo", "bcsr")
+_BLOCK_FORMAT_OF = {"coo": "bcoo", "csr": "bcsr"}
 
 # Plan-IR format version.  Bump when the IR schema changes shape in a way an
 # older reader cannot interpret; ``plan_from_ir`` rejects unknown versions
@@ -166,6 +168,27 @@ def fit_plan(plan: Plan, shape: tuple, n_devices: int,
     return Plan("2d", scheme, fmt, _norm_merge(R, C), (R, C), plan.reason)
 
 
+def check_compiled_pallas(fmt: str) -> None:
+    """Refuse a format whose Pallas kernel Mosaic does not compile for TPU.
+
+    The scalar formats' windowed kernel streams ``(1, CHUNK_E)`` chunk
+    blocks; Mosaic wants a block's last two dims divisible by 8 and 128.
+
+    Raises:
+      ValueError: for ``coo`` and ``csr``.
+    """
+    if fmt in _BLOCK_FORMAT_OF:
+        from repro.kernels.coo_spmv import CHUNK_E
+
+        raise ValueError(
+            f"impl='pallas' with fmt={fmt!r} does not compile for TPU: "
+            f"Mosaic refuses the kernel's (1, {CHUNK_E}) chunk blocks (a "
+            "block's last two dims must divide by 8 and 128); use "
+            "impl='xla', or a block format (bcoo/bcsr) on a shape the "
+            "block tiles"
+        )
+
+
 def resolve_scheme(
     stats: MatrixStats,
     shape: tuple,
@@ -181,17 +204,23 @@ def resolve_scheme(
     fit: bool = True,
     topology=None,
     dtype_bytes: int = 4,
+    compiled_pallas: bool = False,
 ) -> Plan:
     """Turn "auto" / a scheme string / an adaptive.Plan into a fitted Plan.
 
     ``topology`` (a :class:`repro.topo.DeviceTopology`) makes the 2D grid
     fitting collective-cost-aware — see :func:`fit_plan`.
+    ``compiled_pallas`` says the plan runs Pallas kernels compiled for TPU:
+    ``"auto"`` then picks the block counterpart of a scalar format, and a
+    fitted scalar format raises (:func:`check_compiled_pallas`).
     """
     hw = hw if hw is not None else HardwareModel(chips=max(1, n_devices))
     if isinstance(scheme, Plan):
         plan = scheme
     elif scheme == "auto":
         plan = select_scheme(stats, hw)
+        if compiled_pallas:
+            plan = replace(plan, fmt=_BLOCK_FORMAT_OF.get(plan.fmt, plan.fmt))
         if partitioning is not None and plan.partitioning != partitioning:
             if partitioning == "1d":
                 plan = Plan("1d", "nnz", plan.fmt, "ppermute",
@@ -216,6 +245,8 @@ def resolve_scheme(
     if fit:
         plan = fit_plan(plan, shape, n_devices, block, topology=topology,
                         dtype_bytes=dtype_bytes)
+    if compiled_pallas:
+        check_compiled_pallas(plan.fmt)
     return plan
 
 
@@ -234,7 +265,6 @@ class ExecutionPlan:
     mesh: object | None  # None => single-device execution
     dtype: np.dtype
     block: Tuple[int, int] = (8, 16)
-    interpret: bool = True  # pallas interpret mode (CPU validation)
     hw: Optional[HardwareModel] = None
     estimate: dict = field(default_factory=dict)  # analytic Fig.-4 step times
     part: Optional[PartitionedMatrix] = None  # prebuilt partition (optional)
@@ -340,7 +370,7 @@ class ExecutionPlan:
 
         The IR is a plain JSON/msgpack-able dict — no device arrays, no
         mesh object, no matrix payload — capturing scheme, impl, dtype,
-        grid, block, interpret flag, mesh spec (shape + axis names), ring
+        grid, block, mesh spec (shape + axis names), ring
         chunk counts, the analytic estimate and the tuned ``measured``
         metadata.  A worker process rehydrates it against its own device
         pool with :func:`plan_from_ir` and compiles locally; this is how
@@ -380,7 +410,6 @@ class ExecutionPlan:
             "impl": self.impl,
             "dtype": np.dtype(self.dtype).name,
             "block": [int(b) for b in self.block],
-            "interpret": bool(self.interpret),
             "ring": bool(self.ring),
             "ring_counts": (None if self.ring_counts is None
                             else np.asarray(self.ring_counts).tolist()),
@@ -441,10 +470,9 @@ class ExecutionPlan:
                     raise ValueError("the 1D ring schedule runs the XLA local "
                                      "kernel only (impl='xla')")
                 return D.spmv_1d_ring(part, self.ring_counts, self.mesh, axes[0])
-            return D.spmv_1d(part, self.mesh, axes[0], impl=self.impl,
-                             interpret=self.interpret)
+            return D.spmv_1d(part, self.mesh, axes[0], impl=self.impl)
         return D.spmv_2d(part, self.mesh, axes, merge=self.merge,
-                         impl=self.impl, interpret=self.interpret)
+                         impl=self.impl)
 
     def _pallas_extra(self, part: PartitionedMatrix) -> Optional[dict]:
         """Host chunk-plan arrays to place with the matrix (Pallas scalar
@@ -481,8 +509,7 @@ class ExecutionPlan:
         if not self.is_distributed:
             container = self.matrix.container(self.fmt, block=self.block,
                                               dtype=self.dtype)
-            return SingleDeviceExecutor(self, container, self.impl,
-                                        self.interpret)
+            return SingleDeviceExecutor(self, container, self.impl)
         t0 = _time.perf_counter()
         part = self._partition()
         axes = self.axes
@@ -578,8 +605,9 @@ def plan_from_ir(ir: dict, matrix, *, devices=None, mesh=None,
       serialized plan exactly.
 
     Raises:
-      ValueError: unknown ``ir_version``, malformed record, or too few
-        devices for the recorded mesh shape.
+      ValueError: unknown ``ir_version``, malformed record, too few
+        devices for the recorded mesh shape, or a scalar-format Pallas
+        plan loaded onto TPU devices (:func:`check_compiled_pallas`).
     """
     version = ir.get("ir_version")
     if version not in _IR_READABLE:
@@ -634,15 +662,19 @@ def plan_from_ir(ir: dict, matrix, *, devices=None, mesh=None,
             from repro import compat
 
             mesh = compat.make_mesh(shape, axes, devices=devices[:n])
+    if mesh_spec is None:
+        mesh = None
+    if impl == "pallas" and not interpret_mode(
+            None if mesh is None else mesh.devices.flat):
+        check_compiled_pallas(plan.fmt)
     ring_counts = ir.get("ring_counts")
     return ExecutionPlan(
         matrix=matrix,
         scheme=plan,
         impl=impl,
-        mesh=mesh if mesh_spec is not None else None,
+        mesh=mesh,
         dtype=dtype,
         block=block,
-        interpret=bool(ir.get("interpret", True)),
         hw=hw,
         estimate=dict(ir.get("estimate") or {}),
         ring=bool(ir.get("ring", False)),

@@ -42,7 +42,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.cluster.protocol import RemoteError, WorkerLostError
-from repro.cluster.worker import WorkerHandle, spawn_worker
+from repro.cluster.worker import WorkerHandle, spawn_worker, worker_platform
 
 __all__ = ["HashRing", "ClusterEntry", "ClusterRouter"]
 
@@ -156,6 +156,13 @@ class ClusterRouter:
       replicate_check: routed-request cadence of the popularity check.
       socket_dir: AF_UNIX socket directory (default: fresh mkdtemp).
       connect_timeout: per-worker startup allowance (covers JAX import).
+
+    Raises:
+      ValueError: before spawning anything, for fewer than one worker, or
+        for more workers than there are free chips on an accelerator.  A
+        chip belongs to one process, and a worker opens every chip it can
+        see, so an accelerator host runs one worker, and none when this
+        process already holds the chips.
     """
 
     def __init__(
@@ -172,6 +179,14 @@ class ClusterRouter:
     ):
         if workers < 1:
             raise ValueError(f"need at least one worker, got {workers}")
+        platform, chips = worker_platform()
+        if platform != "cpu" and workers > min(1, chips):
+            raise ValueError(
+                f"{workers} workers cannot each open a {platform} chip: "
+                f"{chips} chips are free to a new process, and a worker "
+                f"opens every chip it sees, so at most {min(1, chips)} "
+                "worker can run here"
+            )
         import tempfile
 
         self._lock = threading.RLock()

@@ -40,7 +40,8 @@ from repro.cluster.protocol import (
     send_msg,
 )
 
-__all__ = ["WorkerConfig", "WorkerHandle", "worker_main", "spawn_worker"]
+__all__ = ["WorkerConfig", "WorkerHandle", "worker_main", "spawn_worker",
+           "worker_platform"]
 
 
 @dataclass(frozen=True)
@@ -401,6 +402,55 @@ class WorkerHandle:
             os.unlink(self.address)
         except OSError:
             pass
+
+
+def _report_platform(conn) -> None:
+    """Probe-process body: send back the default backend and its device
+    count, then exit (which releases every chip the probe opened)."""
+    import jax
+
+    platform = jax.default_backend()
+    conn.send((platform, None if platform == "cpu" else len(jax.devices())))
+    conn.close()
+
+
+def worker_platform() -> tuple:
+    """(platform, chips) a newly spawned worker would open.
+
+    Answered without opening a device in this process: from this process's
+    own backend when it is already initialised, from ``JAX_PLATFORMS`` when
+    that names only the CPU, and otherwise by a short-lived ``spawn``-ed
+    probe process that initialises JAX and exits.  ``chips`` is None on
+    the CPU, which has no per-process limit, and 0 on an accelerator whose
+    chips this process already holds.
+    """
+    import multiprocessing
+
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        import jax
+
+        platform = jax.default_backend()
+        return platform, (None if platform == "cpu" else 0)
+    requested = os.environ.get("JAX_PLATFORMS", "")
+    if requested and set(requested.split(",")) == {"cpu"}:
+        return "cpu", None
+    ctx = multiprocessing.get_context("spawn")
+    recv, send = ctx.Pipe(duplex=False)
+    probe = ctx.Process(target=_report_platform, args=(send,), daemon=True)
+    probe.start()
+    send.close()
+    try:
+        if not recv.poll(300.0):
+            raise RuntimeError("the JAX platform probe did not answer")
+        return recv.recv()
+    finally:
+        recv.close()
+        probe.join(timeout=30.0)
+        if probe.is_alive():
+            probe.kill()
+            probe.join(timeout=10.0)
 
 
 def spawn_worker(

@@ -1,126 +1,26 @@
-"""JAX API compatibility shims.
+"""Mesh construction with Auto axis types.
 
-The codebase targets the modern JAX surface (``jax.shard_map``, ``jax.P``,
-``jax.sharding.AxisType``); CI and some dev containers pin older releases
-where those names live under ``jax.experimental.shard_map`` /
-``jax.sharding.PartitionSpec`` and meshes have no axis types.  Everything
-that builds meshes or shard_map programs goes through this module so the
-rest of the code can be written once against the new names.
+``jax.make_mesh`` gives Explicit axes by default.  The SpMV programs place
+arrays with ``NamedSharding`` and run manual collectives inside
+``jax.shard_map``, which is the Auto-axis model, so every mesh in the repo
+is built here.
 """
 from __future__ import annotations
 
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh
 
-__all__ = [
-    "Mesh", "NamedSharding", "P", "shard_map", "make_mesh", "set_mesh",
-    "get_abstract_mesh", "cost_analysis", "scan", "while_loop", "fori_loop",
-    "jit_donated",
-]
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` on new JAX, ``jax.experimental.shard_map`` on old.
-
-    ``check_vma`` (new name) maps onto ``check_rep`` (old name); both default
-    off here because the SpMV programs do manual collectives whose replication
-    the checker cannot see through.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=bool(check_vma),
-    )
+__all__ = ["make_mesh"]
 
 
 def make_mesh(
     axis_shapes: Sequence[int], axis_names: Sequence[str], devices=None
 ) -> Mesh:
-    """Mesh with Auto axis types where the concept exists, plain mesh before."""
-    kwargs = {} if devices is None else {"devices": devices}
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis Auto."""
+    names = tuple(axis_names)
     return jax.make_mesh(
-        tuple(axis_shapes), tuple(axis_names),
-        axis_types=(AxisType.Auto,) * len(tuple(axis_names)), **kwargs
+        tuple(axis_shapes), names, axis_types=(AxisType.Auto,) * len(names),
+        devices=devices,
     )
-
-
-def set_mesh(mesh: Mesh):
-    """Ambient-mesh context: ``jax.set_mesh`` on new JAX; on old JAX the Mesh
-    object is itself the context manager (``with mesh:``)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-def get_abstract_mesh():
-    """The ambient mesh installed by :func:`set_mesh`, or None.
-
-    New JAX exposes it as ``jax.sharding.get_abstract_mesh``; on old JAX the
-    ``with mesh:`` context records the physical mesh in thread resources.
-    """
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is not None:
-        return gam()
-    from jax._src import mesh as mesh_lib
-
-    env_mesh = mesh_lib.thread_resources.env.physical_mesh
-    return None if env_mesh.empty else env_mesh
-
-
-def scan(f, init, xs=None, length=None, **kwargs):
-    """``jax.lax.scan`` with the keywords every pinned release accepts.
-
-    The iterate driver (``repro.api.iterate``) runs its fixed-step solver
-    loops through this single entry point; newer-only keywords (``unroll``
-    etc.) are stripped for releases that predate them rather than crashing
-    the whole loop build.
-    """
-    try:
-        return jax.lax.scan(f, init, xs=xs, length=length, **kwargs)
-    except TypeError:
-        return jax.lax.scan(f, init, xs, length)
-
-
-def while_loop(cond, body, init):
-    """``jax.lax.while_loop`` — stable across pins; routed here so every
-    solver loop (tolerance mode) shares one shim with :func:`scan`."""
-    return jax.lax.while_loop(cond, body, init)
-
-
-def fori_loop(lower, upper, body, init):
-    """``jax.lax.fori_loop`` — the chunked residual-check inner loop."""
-    return jax.lax.fori_loop(lower, upper, body, init)
-
-
-def jit_donated(f, donate_argnums=()):
-    """``jax.jit`` with donated arguments, degrading to plain jit.
-
-    Buffer donation lets the solver loops reuse the carry's device memory
-    across iterations (x never round-trips, and never doubles up).  Some
-    backends/pins reject donation (CPU historically warned or threw for
-    some aval layouts); the loop must still run, just without the aliasing.
-    """
-    try:
-        return jax.jit(f, donate_argnums=donate_argnums)
-    except TypeError:
-        return jax.jit(f)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a dict (old JAX wrapped it in a list)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
-    return ca

@@ -36,7 +36,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map as _shard_map
 from repro.core.partition import PartitionedMatrix
 from repro.kernels import ref as kref
 
@@ -125,7 +124,7 @@ def pallas_chunk_arrays(mat: PartitionedMatrix, chunk: int | None = None) -> dic
             if isinstance(v, np.ndarray)}
 
 
-def _local_kernel(mat: PartitionedMatrix, impl: str, interpret: bool):
+def _local_kernel(mat: PartitionedMatrix, impl: str, mesh):
     """Build the per-shard kernel ``f(sl, x_local) -> y (h_pad[, B])``.
 
     impl="xla" dispatches the jnp oracles (lower everywhere, shard-safe);
@@ -134,12 +133,16 @@ def _local_kernel(mat: PartitionedMatrix, impl: str, interpret: bool):
     :func:`pallas_chunk_arrays` and carried in the placed arrays under
     ``chunk_*``), the block kernel for BCSR/BCOO.  Both impls return the
     values dtype (accumulation happens wider inside, matching the oracle
-    contract the merge collectives rely on).
+    contract the merge collectives rely on).  The Pallas kernels compile
+    for a TPU mesh and run in interpret mode on a CPU mesh.
     """
     if impl == "xla":
         return lambda sl, x_local: _local_spmv(mat, sl, x_local)
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}: 'xla' or 'pallas'")
+    from repro.kernels.mode import interpret_mode
+
+    interpret = interpret_mode(mesh.devices.flat)
     dtype = mat.dtype
 
     if mat.fmt in ("coo", "csr"):
@@ -249,7 +252,6 @@ def spmv_1d(
     axis: str = "data",
     x_sharding_axis: str | None = None,
     impl: str = "xla",
-    interpret: bool = True,
 ) -> callable:
     """Build jitted distributed 1D SpMV: (placed_arrays, x) -> SpmvOutput.
 
@@ -270,7 +272,7 @@ def spmv_1d(
     rp = jnp.asarray(recv_pos.astype(np.int32))
     needs_merge = mat.scheme == "1d.nnz"
     perm = [(i, i - 1) for i in range(1, Pn)]
-    local = _local_kernel(mat, impl, interpret)
+    local = _local_kernel(mat, impl, mesh)
 
     def _step(arrs, hs_l, ns_l, rp_l, x_shard):
         sl = _slice0(arrs)
@@ -283,7 +285,7 @@ def spmv_1d(
             y = y.at[rp_l[0]].add(jnp.where(ns_l[0], recv, jnp.zeros_like(recv)))
         return y[None]
 
-    shmap = _shard_map(
+    shmap = jax.shard_map(
         _step,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
@@ -446,7 +448,7 @@ def spmv_1d_ring(
             y = y.at[rp_l[0]].add(jnp.where(ns_l[0], recv, jnp.zeros_like(recv)))
         return y[None]
 
-    shmap = _shard_map(
+    shmap = jax.shard_map(
         _step,
         mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis), P(axis)),
@@ -482,7 +484,6 @@ def spmv_2d(
     axes: Tuple[str, str] = ("data", "model"),
     merge: str | None = None,
     impl: str = "xla",
-    interpret: bool = True,
 ) -> callable:
     """Build jitted distributed 2D SpMV: (placed_arrays, x) -> SpmvOutput.
 
@@ -496,7 +497,7 @@ def spmv_2d(
                         whole mesh — faithful to the paper's retrieve+merge
                         path and its bottleneck (Obs. 12).
 
-    ``impl``/``interpret`` select the per-shard tile kernel exactly as in
+    ``impl`` selects the per-shard tile kernel exactly as in
     :func:`spmv_1d` (Pallas scalar formats need the placed ``chunk_*``
     arrays, via ``place_2d(..., extra=pallas_chunk_arrays(mat))``).
     """
@@ -516,7 +517,7 @@ def spmv_2d(
     if aligned and mat.shape[0] % R != 0:
         raise ValueError("equally-sized needs rows % R == 0")
     rows_pad = mat.h_pad * R if aligned else -(-mat.shape[0] // 8) * 8
-    local = _local_kernel(mat, impl, interpret)
+    local = _local_kernel(mat, impl, mesh)
 
     def _step(arrs, x_shard):
         sl = _slice0(jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), arrs))
@@ -553,7 +554,7 @@ def spmv_2d(
         return buf[None, None]
 
     out_spec = P(da, ma) if merge != "global" else P(None, None)
-    shmap = _shard_map(
+    shmap = jax.shard_map(
         _step,
         mesh=mesh,
         in_specs=(P(da, ma), P(ma)),

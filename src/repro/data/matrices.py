@@ -74,13 +74,21 @@ def scale_free_matrix(rows: int, cols: int, nnz_target: int, seed: int = 0,
 
 def block_matrix(rows: int, cols: int, block=(8, 16), block_density=0.08,
                  seed: int = 0, dtype=np.float32) -> np.ndarray:
-    """Dense r x c blocks on a sparse block grid (block_fill ~ 1.0)."""
+    """Dense r x c blocks on a sparse block grid (block_fill ~ 1.0).
+
+    Values are ``kron(mask, ones((r, c))) * standard_normal((rows, cols))``,
+    drawn one block-row at a time so the host holds no float64 temporary
+    of the whole matrix.
+    """
     rng = np.random.default_rng(seed)
     r, c = block
     assert rows % r == 0 and cols % c == 0
     mask = rng.random((rows // r, cols // c)) < block_density
-    a = np.kron(mask, np.ones((r, c))).astype(dtype)
-    return a * rng.standard_normal((rows, cols)).astype(dtype)
+    a = np.empty((rows, cols), dtype)
+    for i in range(rows // r):
+        a[i * r:(i + 1) * r] = (np.repeat(mask[i], c)
+                                * rng.standard_normal((r, cols)))
+    return a
 
 
 @dataclass(frozen=True)

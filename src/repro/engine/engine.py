@@ -30,6 +30,7 @@ from repro.core.adaptive import HardwareModel, Plan
 from repro.engine.plan_cache import CompiledPlan, PlanCache, PlanKey
 from repro.engine.registry import MatrixRegistry, RegisteredMatrix
 from repro.engine.telemetry import RequestRecord, Telemetry
+from repro.kernels.mode import interpret_mode
 from repro.obs import profile as obs_profile
 
 __all__ = ["SpmvEngine"]
@@ -67,7 +68,8 @@ class SpmvEngine:
           block: (r, c) block shape for the block formats and matrix stats.
           hw: HardwareModel driving adaptive scheme selection.
           impl: default local tile kernel for registered matrices — "xla"
-            (oracles) or "pallas" (TPU kernels; interpret mode off-TPU).
+            (oracles) or "pallas" (TPU kernels: compiled on TPU, where
+            only the block formats compile, and interpreted on CPU).
             ``register(..., impl=...)`` overrides per matrix.
           tune: measure-and-refine plans in the background off live traffic
             (:mod:`repro.tune`): once a matrix has served ``tune_after``
@@ -289,6 +291,8 @@ class SpmvEngine:
             sm.stats, sm.shape, self.n_devices,
             plan if plan is not None else "auto",
             hw=self.hw, partitioning=partitioning, block=self.block,
+            compiled_pallas=(impl == "pallas"
+                             and not interpret_mode(self.devices)),
         )
         fp = sm.fingerprint()
         scheme_id = plan.tag

@@ -11,8 +11,10 @@ Modules:
   ell_spmv.py    padded-row gather kernel (beyond-paper TPU-native format)
   ops.py         public dispatch (impl="xla" | "pallas"), spmv/spmm
   instrument.py  trace-time kernel-build counters (test observability)
+  mode.py        interpret_mode: compiled on TPU, interpreted on CPU
 """
 from . import ref  # noqa: F401
+from .mode import interpret_mode  # noqa: F401
 from .ops import (  # noqa: F401
     pallas_program,
     spmm,

@@ -33,6 +33,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .instrument import record_build
+from .mode import interpret_mode
 
 __all__ = ["bcoo_spmv_pallas", "DEFAULT_BLOCK", "BATCH_TILE"]
 
@@ -69,7 +70,9 @@ def _kernel(browind_ref, bcolind_ref, nb_ref, bval_ref, x_ref, y_ref):
     a = bval_ref[0]  # (r, c)
     xb = x_ref[...]  # (c, B) window at block-column bcolind[i]
     acc = y_ref.dtype
-    prod = jnp.dot(a.astype(acc), xb.astype(acc), preferred_element_type=acc)
+    # HIGHEST: at default precision the TPU multiplies f32 in one bf16 pass
+    prod = jnp.dot(a.astype(acc), xb.astype(acc), preferred_element_type=acc,
+                   precision=jax.lax.Precision.HIGHEST)
     y_ref[...] += jnp.where(valid, prod, 0)
 
 
@@ -80,7 +83,7 @@ def bcoo_spmv_pallas(
     x: jax.Array,
     out_rows: int,
     nblocks: jax.Array | int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
     batch_tile: int | None = None,
 ) -> jax.Array:
     """Block-sparse y = A @ x, A given as a block-row-sorted BCOO stream.
@@ -95,7 +98,8 @@ def bcoo_spmv_pallas(
         (r, c) x (c, BT) MXU issue per batch tile.
       out_rows: static output height (multiple of r).
       nblocks: true nonzero-block count (<= nb_cap); None means all.
-      interpret: execute the kernel body in Python (CPU validation mode).
+      interpret: execute the kernel body in Python; None decides from the
+        default backend (:func:`~repro.kernels.mode.interpret_mode`).
       batch_tile: RHS columns per grid step; default ``min(B, BATCH_TILE)``.
 
     Returns y (out_rows[, B]) in the accumulation dtype (f32 for bf16 input,
@@ -121,6 +125,8 @@ def bcoo_spmv_pallas(
 
     acc = _acc_dtype(bvalues.dtype)
     record_build("bcoo", B)
+    if interpret is None:
+        interpret = interpret_mode()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b_pad // bt, nb_cap),

@@ -34,6 +34,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .instrument import record_build
+from .mode import interpret_mode
 
 __all__ = ["coo_spmv_pallas", "plan_chunks", "stack_chunk_plans", "ChunkPlan",
            "CHUNK_E", "ROW_SPAN", "BATCH_TILE"]
@@ -159,13 +160,15 @@ def _kernel(win_ref, cnt_ref, ri_ref, ci_ref, val_ref, x_ref, y_ref):
     span = y_ref.shape[0]
     # Lock-free merge on the MXU: scatter rel-rows as a one-hot matmul.
     onehot = (rel[:, None] == jnp.arange(span, dtype=jnp.int32)[None, :]).astype(acc)
-    y_ref[...] += jnp.dot(onehot.T, prod, preferred_element_type=acc)
+    # HIGHEST: at default precision the TPU multiplies f32 in one bf16 pass
+    y_ref[...] += jnp.dot(onehot.T, prod, preferred_element_type=acc,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 def coo_spmv_pallas(
     plan: ChunkPlan,
     x: jax.Array,
-    interpret: bool = True,
+    interpret: bool | None = None,
     batch_tile: int | None = None,
 ) -> jax.Array:
     """Run the windowed COO kernel for a ChunkPlan (SpMV or multi-RHS SpMM).
@@ -176,7 +179,8 @@ def coo_spmv_pallas(
         leading lane-tiled batch axis: B is padded to a multiple of
         ``batch_tile`` lanes and each grid step works on one (chunk, lane
         tile) pair, reusing the same chunk stream across tiles.
-      interpret: run the kernel body in interpret mode (CPU validation).
+      interpret: run the kernel body in interpret mode; None decides from
+        the default backend (:func:`~repro.kernels.mode.interpret_mode`).
       batch_tile: RHS columns per grid step; default ``min(B, BATCH_TILE)``.
 
     Returns:
@@ -199,6 +203,8 @@ def coo_spmv_pallas(
         y = jnp.zeros((plan.out_rows, B), acc)
         return y[:, 0] if squeeze else y
     record_build("coo", B)
+    if interpret is None:
+        interpret = interpret_mode()
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

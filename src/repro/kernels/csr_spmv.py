@@ -49,7 +49,7 @@ def csr_plan_chunks(
     )
 
 
-def csr_spmv_pallas(plan: ChunkPlan, x: jax.Array, interpret: bool = True,
+def csr_spmv_pallas(plan: ChunkPlan, x: jax.Array, interpret: bool | None = None,
                     batch_tile: int | None = None):
     """CSR SpMV/SpMM — same windowed kernel, row-granular chunk plan.
 

@@ -21,6 +21,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from .instrument import record_build
+from .mode import interpret_mode
 
 __all__ = ["ell_spmv_pallas", "dense_to_ell", "ROW_TILE", "BATCH_TILE"]
 
@@ -68,7 +69,7 @@ def ell_spmv_pallas(
     values: jax.Array,
     row_nnz: jax.Array,
     x: jax.Array,
-    interpret: bool = True,
+    interpret: bool | None = None,
     row_tile: int = ROW_TILE,
     batch_tile: int | None = None,
 ) -> jax.Array:
@@ -79,7 +80,8 @@ def ell_spmv_pallas(
       row_nnz: (rows,) real slots per row; the tail is masked.
       x: (cols,) or (cols, B).  B > 1 adds a lane-tiled batch grid axis:
         each grid step computes a (row tile, batch tile) output block.
-      interpret: run the kernel body in interpret mode (CPU validation).
+      interpret: run the kernel body in interpret mode; None decides from
+        the default backend (:func:`~repro.kernels.mode.interpret_mode`).
       row_tile: rows per grid step (8-sublane aligned).
       batch_tile: RHS columns per grid step; default ``min(B, BATCH_TILE)``.
 
@@ -102,6 +104,8 @@ def ell_spmv_pallas(
         row_nnz = jnp.pad(row_nnz, (0, pad_rows - rows))
     acc = _acc_dtype(values.dtype)
     record_build("ell", B)
+    if interpret is None:
+        interpret = interpret_mode()
     y = pl.pallas_call(
         _kernel,
         grid=(pad_rows // T, b_pad // bt),

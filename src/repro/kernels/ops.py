@@ -4,8 +4,8 @@ Two implementations per format:
   * ``impl="xla"``    — the pure-jnp oracle path (kernels/ref.py).  Lowers on
     every backend; used inside shard_map for the multi-pod dry-run and as the
     CPU production path.
-  * ``impl="pallas"`` — the TPU kernels (interpret=True on CPU for
-    validation; compiled on real TPUs).
+  * ``impl="pallas"`` — the TPU kernels (compiled on TPU, interpreted on
+    CPU for validation; :func:`~repro.kernels.mode.interpret_mode`).
 
 `spmv` takes the container formats from core/formats.py; `spmv_local_coo`
 is the flat-argument variant the distributed layer calls per shard.
@@ -46,7 +46,7 @@ def _require_concrete(m) -> None:
         )
 
 
-def spmv(m, x: jax.Array, impl: str = "xla", interpret: bool = True) -> jax.Array:
+def spmv(m, x: jax.Array, impl: str = "xla") -> jax.Array:
     """y = m @ x for any SparseP container format (single device).
 
     For ``impl="pallas"`` on the scalar formats the (static) chunk plan is
@@ -67,12 +67,11 @@ def spmv(m, x: jax.Array, impl: str = "xla", interpret: bool = True) -> jax.Arra
             )
         raise TypeError(type(m))
     if impl == "pallas":
-        return pallas_program(m, interpret=interpret)(x)
+        return pallas_program(m)(x)
     raise ValueError(f"unknown impl {impl!r}")
 
 
-def pallas_program(m, interpret: bool = True,
-                   batch_tile: int | None = None):
+def pallas_program(m, batch_tile: int | None = None):
     """Build the Pallas SpMV/SpMM callable for a container (plan once).
 
     The host-side preprocessing (chunk planning for COO/CSR, browptr
@@ -83,7 +82,6 @@ def pallas_program(m, interpret: bool = True,
 
     Args:
       m: a concrete CSR/COO/BCSR/BCOO container (``core.formats``).
-      interpret: run the kernels in interpret mode (CPU validation).
       batch_tile: SpMM lane tile override (see the kernel modules).
 
     Returns:
@@ -101,8 +99,7 @@ def pallas_program(m, interpret: bool = True,
             np.asarray(m.rowptr), np.asarray(m.colind), np.asarray(m.values),
             m.rows,
         )
-        return partial(csr_spmv_pallas, plan, interpret=interpret,
-                       batch_tile=batch_tile)
+        return partial(csr_spmv_pallas, plan, batch_tile=batch_tile)
     if isinstance(m, F.COO):
         nnz = int(m.nnz)
         plan = plan_chunks(
@@ -111,8 +108,7 @@ def pallas_program(m, interpret: bool = True,
             np.asarray(m.values)[:nnz],
             m.rows,
         )
-        return partial(coo_spmv_pallas, plan, interpret=interpret,
-                       batch_tile=batch_tile)
+        return partial(coo_spmv_pallas, plan, batch_tile=batch_tile)
     if isinstance(m, (F.BCSR, F.BCOO)):
         browind = (_bcsr_to_bcoo_indices(m) if isinstance(m, F.BCSR)
                    else m.browind)
@@ -120,14 +116,14 @@ def pallas_program(m, interpret: bool = True,
         def run(x):
             return bcoo_spmv_pallas(
                 browind, m.bcolind, m.bvalues, x, m.rows, m.nblocks,
-                interpret=interpret, batch_tile=batch_tile,
+                batch_tile=batch_tile,
             )
 
         return run
     raise TypeError(type(m))
 
 
-def spmm(m, X: jax.Array, impl: str = "xla", interpret: bool = True) -> jax.Array:
+def spmm(m, X: jax.Array, impl: str = "xla") -> jax.Array:
     """Multi-RHS SpMV: Y = m @ X with X of shape (cols, B) -> (rows, B).
 
     For ``impl="xla"`` the batch dimension threads through every oracle in
@@ -141,7 +137,6 @@ def spmm(m, X: jax.Array, impl: str = "xla", interpret: bool = True) -> jax.Arra
       m: any container format from core/formats.py.
       X: (cols, B) right-hand sides.
       impl: "xla" or "pallas" (concrete containers only, like ``spmv``).
-      interpret: Pallas interpret mode (CPU validation).
 
     Returns:
       Y (rows, B); for "pallas" in the kernel accumulation dtype.
@@ -153,7 +148,7 @@ def spmm(m, X: jax.Array, impl: str = "xla", interpret: bool = True) -> jax.Arra
     X = jnp.asarray(X)
     if X.ndim != 2:
         raise ValueError(f"spmm expects X of shape (cols, B); got {X.shape}")
-    return spmv(m, X, impl=impl, interpret=interpret)
+    return spmv(m, X, impl=impl)
 
 
 def _bcsr_to_bcoo_indices(m: F.BCSR) -> jax.Array:

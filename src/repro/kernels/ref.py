@@ -102,7 +102,9 @@ def bcoo_spmv_ref(
     xb = x.reshape((x.shape[0] // c, c) + x.shape[1:])  # (bc, c, ...)
     xg = jnp.take(xb, bcolind, axis=0, mode="clip").astype(acc)  # (nb, c, ...)
     # per-block product on the MXU: (nb, r, c) x (nb, c, ...) -> (nb, r, ...)
-    prod = jnp.einsum("krc,kc...->kr...", bvalues.astype(acc), xg)
+    # HIGHEST: at default precision the TPU multiplies f32 in one bf16 pass
+    prod = jnp.einsum("krc,kc...->kr...", bvalues.astype(acc), xg,
+                      precision=jax.lax.Precision.HIGHEST)
     prod = jnp.where(valid[(...,) + (None,) * (prod.ndim - 1)], prod, 0)
     yb = jnp.zeros((out_rows // r, r) + x.shape[1:], acc)
     yb = yb.at[browind].add(prod, mode="drop")

@@ -5,7 +5,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 For each (architecture x input shape x mesh) cell:
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered  = jax.jit(step, in_shardings=..., donate...).lower(*input_specs)
         compiled = lowered.compile()
         print(compiled.memory_analysis())   # proves it fits per device
@@ -34,8 +34,6 @@ import traceback
 
 import jax
 
-from repro import compat
-
 from repro.analysis import roofline as R
 from repro.configs import get_config, list_configs
 from repro.configs.base import SHAPES
@@ -44,6 +42,10 @@ from repro.launch.mesh import make_production_mesh
 from repro.models import lm
 from repro.optim import AdamWConfig
 from repro.runtime import make_shardings
+
+
+#: The chip the pod meshes model (its peaks come from analysis/roofline.py).
+TARGET_KIND = "TPU v5 lite"
 
 
 def _mesh(multi_pod: bool):
@@ -77,7 +79,7 @@ def lower_cell(cfg, shape_name: str, mesh, donate=True, microbatches=None):
     )
     if microbatches is None:
         microbatches = _microbatches(cfg, shape_name)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if kind == "train":
             opt_cfg = _opt_cfg(cfg)
             params, opt = S.abstract_state(cfg, opt_cfg)
@@ -165,7 +167,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, probe: bool,
     lowered, compiled = lower_cell(cfg, shape_name, mesh)
     dt = time.monotonic() - t0
     mem = compiled.memory_analysis()
-    ca = compat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     coll = R.collective_bytes(compiled.as_text())
     print(f"[ok] {label} compiled in {dt:.1f}s")
     print(f"     memory_analysis: {mem}")
@@ -196,7 +198,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, probe: bool,
     }
     if probe:
         total = probe_cell(cfg, shape_name, mesh)
-        rec["roofline"] = R.roofline_report(cfg, shape_name, chips, total)
+        rec["roofline"] = R.roofline_report(cfg, shape_name, chips, total,
+                                            TARGET_KIND)
         rl = rec["roofline"]
         print(f"     roofline: compute={rl['compute_s']:.3e}s "
               f"memory={rl['memory_s']:.3e}s coll={rl['collective_s']:.3e}s "
@@ -220,6 +223,9 @@ def main(argv=None):
                     help="skip cells whose JSON (incl. probe, if requested) exists")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args(argv)
+    from repro.runtime.cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     def _have(arch, shape_name, mp):
         fn = os.path.join(

@@ -75,7 +75,7 @@ def lower_1d(mat, mesh, ring=False):
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), D._arrays(mat)
     )
     x_aval = jax.ShapeDtypeStruct((mat.shape[1],), jnp.float32)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = fn.jitted.lower(arrs_aval, x_aval)
     return lowered, lowered.compile()
 
@@ -86,6 +86,9 @@ def main(argv=None):
     ap.add_argument("--nnz-per-row", type=int, default=16)
     ap.add_argument("--out", default="experiments/dryrun_spmv.json")
     args = ap.parse_args(argv)
+    from repro.runtime.cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     recs = []
     for multi_pod in (False, True):
@@ -99,7 +102,7 @@ def main(argv=None):
             label = f"spmv.1d{'.ring' if ring else ''}.{pod}"
             lowered, compiled = lower_1d(mat, flat, ring=ring)
             mem = compiled.memory_analysis()
-            ca = compat.cost_analysis(compiled)
+            ca = compiled.cost_analysis()
             coll = R.collective_bytes(compiled.as_text())
             rec = {
                 "name": label,

@@ -16,7 +16,6 @@ import time
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 
@@ -34,7 +33,7 @@ class Server:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
         pspecs = lm.param_specs(cfg)
         self.p_sh = make_shardings(mesh, pspecs)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             self.params = jax.jit(
                 lambda k: lm.init_params(k, cfg), out_shardings=self.p_sh
             )(jax.random.PRNGKey(0))
@@ -47,7 +46,7 @@ class Server:
 
     def generate(self, prompts: np.ndarray, n_tokens: int):
         """prompts: (B, S) int32. Greedy decode n_tokens. Returns (B, n)."""
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             logits, caches = self._prefill(self.params, jnp.asarray(prompts))
             tok = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
             out = [tok]
@@ -66,6 +65,9 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args(argv)
+    from repro.runtime.cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -19,7 +19,6 @@ from functools import partial
 
 import jax
 
-from repro import compat
 from repro.configs import get_config
 from repro.data import TokenStream
 from repro.launch import steps as S
@@ -72,7 +71,7 @@ class TrainLoop:
         base_step = S.make_train_step(cfg, opt_cfg)
         if compress_pod_grads:
             base_step = self._wrap_compressed(base_step)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             self.step_fn = jax.jit(
                 base_step,
                 in_shardings=(self.p_sh, self.o_sh, self.b_sh)
@@ -110,7 +109,7 @@ class TrainLoop:
     # -- state ---------------------------------------------------------------
 
     def init_state(self, seed: int = 0):
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             init = jax.jit(
                 partial(lm.init_params, cfg=self.cfg),
                 out_shardings=self.p_sh,
@@ -196,6 +195,9 @@ def main(argv=None):
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--compress-pod-grads", action="store_true")
     args = ap.parse_args(argv)
+    from repro.runtime.cache import enable_compilation_cache
+
+    enable_compilation_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

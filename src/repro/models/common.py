@@ -18,7 +18,6 @@ from typing import Any
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -50,7 +49,7 @@ def batch_axes(mesh=None) -> tuple:
 
 
 def _mesh_axis_names():
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     return m.axis_names if m is not None and m.axis_names else ()
 
 
@@ -66,7 +65,7 @@ def shard(x: jax.Array, *spec) -> jax.Array:
         full rematerializations (64 GiB/layer score all-gathers observed on
         llama's GQA in the roofline probes).
     """
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     names = m.axis_names if m is not None and m.axis_names else ()
     if not names:
         return x

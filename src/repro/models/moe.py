@@ -27,7 +27,6 @@ from typing import NamedTuple
 
 import jax
 
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -134,7 +133,7 @@ def _group_axes(cfg) -> tuple:
 
 def _n_batch_shards(axes) -> int:
     """Shard-group count over ``axes`` from the ambient mesh (1 without)."""
-    m = compat.get_abstract_mesh()
+    m = jax.sharding.get_abstract_mesh()
     if m is None or not m.axis_names:
         return 1
     sizes = dict(m.shape)

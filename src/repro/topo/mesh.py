@@ -5,8 +5,7 @@ logical mesh shape, pick (or accept) an :class:`~repro.topo.AxisAssignment`
 and build the mesh with the device order that realizes it — the
 ``jax.experimental.mesh_utils`` contiguous-mesh trick, where each logical
 axis's neighbours sit on the physical links assigned to it.  All mesh
-construction goes through :func:`repro.compat.make_mesh` (ROADMAP carry-over
-constraint: compat bridges modern JAX to the 0.4.x pins).
+construction goes through :func:`repro.compat.make_mesh` (Auto axis types).
 """
 from __future__ import annotations
 

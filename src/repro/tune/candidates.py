@@ -7,7 +7,9 @@ crossed with the requested kernel impls, fitted to the pool by the same
 ``repro.api.fit_plan`` rules every other entry point uses, and deduplicated
 by fitted identity.  Candidates that cannot be planned on the given
 mesh/devices (grid-shape mismatch, unfit formats) are silently dropped —
-the tuner only measures what would actually compile.
+the tuner only measures what would actually compile.  On TPU that
+excludes the scalar formats under ``impl="pallas"``, whose kernel Mosaic
+refuses (``repro.api.plan.check_compiled_pallas``).
 """
 
 from __future__ import annotations
@@ -44,7 +46,6 @@ class CandidateGenerator:
         mesh=None,
         block: Tuple[int, int] = (8, 16),
         hw: Optional[HardwareModel] = None,
-        interpret: bool = True,
         topology=None,
     ) -> list:
         """Candidate ExecutionPlans for ``matrix`` on the given pool.
@@ -57,7 +58,6 @@ class CandidateGenerator:
           block: (r, c) tile for the block formats.
           hw: HardwareModel for the analytic ranking (default: one chip per
             device in the pool).
-          interpret: Pallas interpret mode (keep True off-TPU).
           topology: a :class:`repro.topo.DeviceTopology` — each distributed
             candidate is then expanded into one plan *per viable axis
             assignment* (model-ranked order), so the measurements can
@@ -104,7 +104,6 @@ class CandidateGenerator:
                         devices=devices,
                         block=block,
                         hw=hw,
-                        interpret=interpret,
                         topology=topology,
                     )
                 except ValueError:
@@ -126,7 +125,7 @@ class CandidateGenerator:
                     try:
                         _admit(matrix.plan(
                             scheme=plan.scheme, impl=impl, devices=devices,
-                            block=block, hw=hw, interpret=interpret,
+                            block=block, hw=hw,
                             topology=topology, assignment=alt,
                         ))
                     except ValueError:

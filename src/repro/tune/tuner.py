@@ -86,7 +86,6 @@ class Tuner:
         mesh=None,
         block: Tuple[int, int] = (8, 16),
         hw: Optional[HardwareModel] = None,
-        interpret: bool = True,
         batch: Optional[int] = None,
         x=None,
         baseline: Optional[Tuple[Plan, str]] = None,
@@ -99,7 +98,6 @@ class Tuner:
           devices/mesh: device pool (omit both for single-device tuning).
           block: (r, c) tile for the block formats.
           hw: HardwareModel for candidate enumeration/estimates.
-          interpret: Pallas interpret mode (keep True off-TPU).
           batch: representative batch width B (keyed into the cache: the
             winner for B=1 SpMV and B=32 SpMM may legitimately differ).
           x: representative input override; default is the measurer's
@@ -126,11 +124,11 @@ class Tuner:
             return self._from_record(
                 matrix, record, key,
                 devices=devices, mesh=mesh, block=block, hw=hw,
-                interpret=interpret, baseline=baseline, topology=topology,
+                baseline=baseline, topology=topology,
             )
         plans = self.generator.plans(
             matrix, devices=devices, mesh=mesh, block=block, hw=hw,
-            interpret=interpret, topology=topology,
+            topology=topology,
         )
         if baseline is not None:
             base_plan, base_impl = baseline
@@ -138,7 +136,7 @@ class Tuner:
             try:
                 inc = matrix.plan(
                     scheme=base_plan, impl=base_impl, devices=devices,
-                    mesh=mesh, block=block, hw=hw, interpret=interpret,
+                    mesh=mesh, block=block, hw=hw,
                     topology=topology,
                 )
                 if (inc.scheme_id, inc.impl) not in have:
@@ -255,7 +253,7 @@ class Tuner:
 
     def _from_record(
         self, matrix, record: dict, key: TuneKey, *,
-        devices, mesh, block, hw, interpret, baseline=None, topology=None,
+        devices, mesh, block, hw, baseline=None, topology=None,
     ) -> TuningResult:
         """Rebuild the cached winner WITHOUT re-measuring (the cache's whole
         point: re-register never pays the measurement loop again)."""
@@ -267,7 +265,6 @@ class Tuner:
             scheme=record_to_plan(record),
             impl=record.get("impl", "xla"),
             devices=devices, mesh=mesh, block=block, hw=hw,
-            interpret=interpret,
             topology=topology, assignment=assignment,
         )
         best_m = Measurement(

@@ -10,7 +10,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import compat
-from repro.compat import P
+from jax.sharding import PartitionSpec as P
 from repro.core.partition import partition_1d, partition_2d
 from repro.core import distributed as D
 

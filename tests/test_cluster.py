@@ -326,6 +326,47 @@ def _cluster_mats():
     return mats
 
 
+def test_router_refuses_more_workers_than_free_chips(monkeypatch):
+    """On an accelerator a worker opens every chip it sees, so the router
+    refuses a second worker, and any worker while this process holds the
+    chips — before it spawns anything."""
+    from repro.cluster import router as R
+
+    def no_spawn(*args, **kwargs):
+        raise AssertionError("a worker was spawned")
+
+    monkeypatch.setattr(R, "spawn_worker", no_spawn)
+    monkeypatch.setattr(R, "worker_platform", lambda: ("tpu", 4))
+    with pytest.raises(ValueError, match="at most 1 worker"):
+        R.ClusterRouter(workers=2)
+    monkeypatch.setattr(R, "worker_platform", lambda: ("tpu", 0))
+    with pytest.raises(ValueError, match="at most 0 worker"):
+        R.ClusterRouter(workers=1)
+
+
+def test_router_process_never_opens_a_device():
+    """The router's own process imports JAX but never initialises a
+    backend, so on a chip host its one worker can open the chip."""
+    script = r"""
+import sys
+sys.path.insert(0, {src!r})
+import numpy as np
+from jax._src import xla_bridge
+from repro.cluster import ClusterRouter
+
+a = np.eye(16, dtype=np.float32) * 3.0
+x = np.arange(16, dtype=np.float32)
+with ClusterRouter(workers=1, connect_timeout=300.0) as router:
+    router.register("a", a)
+    assert np.array_equal(router.multiply("a", x), a @ x)
+print("BACKEND_INITIALISED", xla_bridge.backends_are_initialized())
+""".format(src=os.path.join(ROOT, "src"))
+    proc = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                          text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "BACKEND_INITIALISED False" in proc.stdout
+
+
 @pytest.fixture(scope="module")
 def cluster():
     from repro.cluster import ClusterRouter

@@ -35,7 +35,7 @@ for arch in ("llama3.2-1b", "mixtral-8x22b", "xlstm-1.3b"):
         # 64-dim model on 8 fake devices compiles in seconds.
         lowered, compiled = lower_cell(cfg, shape, mesh, microbatches=1)
         mem = compiled.memory_analysis()
-        ca = compat.cost_analysis(compiled)
+        ca = compiled.cost_analysis()
         coll = R.collective_bytes(compiled.as_text())
         assert mem.temp_size_in_bytes > 0
         assert ca.get("flops", 0) > 0

@@ -68,3 +68,21 @@ def test_empty_matrix():
         np.testing.assert_array_equal(np.asarray(F.to_dense(m)), a)
     mb = F.dense_to_bcoo(a, block=(4, 4))
     np.testing.assert_array_equal(np.asarray(F.to_dense(mb)), a)
+
+
+@pytest.mark.parametrize("shape,block,density,seed", [
+    ((48, 64), (8, 16), 0.3, 3), ((96, 256), (8, 128), 0.5, 1),
+])
+def test_block_matrix_matches_the_kron_formula(shape, block, density, seed):
+    """The generator draws block-row by block-row; its values must equal
+    the whole-matrix formula it documents, bit for bit."""
+    from repro.data.matrices import block_matrix
+
+    rng = np.random.default_rng(seed)
+    r, c = block
+    mask = rng.random((shape[0] // r, shape[1] // c)) < density
+    want = (np.kron(mask, np.ones((r, c))).astype(np.float32)
+            * rng.standard_normal(shape).astype(np.float32))
+    got = block_matrix(*shape, block=block, block_density=density, seed=seed)
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(got, want)

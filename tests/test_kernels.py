@@ -145,18 +145,49 @@ def test_spmm_parity_pallas_xla_dense(fmt, dtype, batch):
             ops.spmm(m, jnp.asarray(X), impl="pallas"))[:, 0], y)
 
 
-def test_spmm_batch_tiling_is_invariant():
-    """Lane-tiled batch grids (including ragged B) match the untiled result."""
-    from repro.kernels.coo_spmv import coo_spmv_pallas, plan_chunks
+@pytest.mark.parametrize("values", ["integer", "float"])
+def test_spmm_batch_tiling_is_invariant(values):
+    """Lane-tiled batch grids (including ragged B) match the untiled result.
 
-    a = rand_sparse(70, 90, 0.1, np.float32, seed=43)
+    The batch a served request lands in must not change its answer beyond
+    float32 rounding (docs/kernels.md).  The merge matmul may sum in another
+    order per tile width, so integer-valued data, whose sums are exact in
+    any order, must match bit for bit, and float data to 1e-5 of the scale.
+    """
+    rng = np.random.default_rng(44)
+    if values == "integer":
+        a = rand_sparse(70, 90, 0.1, np.int32, seed=43).astype(np.float32)
+        X = rng.integers(-4, 5, (90, 6)).astype(np.float32)
+    else:
+        a = rand_sparse(70, 90, 0.1, np.float32, seed=43)
+        X = rng.standard_normal((90, 6)).astype(np.float32)
     ri, ci = np.nonzero(a)
     plan = plan_chunks(ri, ci, a[ri, ci], 70, chunk=64, span=64)
-    X = np.random.default_rng(44).standard_normal((90, 6)).astype(np.float32)
     base = np.asarray(coo_spmv_pallas(plan, jnp.asarray(X)))
     for bt in (1, 2, 4):  # 6 % 4 != 0 exercises the batch-pad path
         tiled = np.asarray(coo_spmv_pallas(plan, jnp.asarray(X), batch_tile=bt))
-        np.testing.assert_array_equal(tiled, base)
+        if values == "integer":
+            np.testing.assert_array_equal(tiled, base)
+        else:
+            np.testing.assert_allclose(tiled, base, rtol=1e-5,
+                                       atol=1e-5 * np.abs(base).max())
+
+
+def test_interpret_mode_follows_the_platform():
+    """Compiled on TPU, interpreted on CPU, refused anywhere else."""
+    from types import SimpleNamespace
+
+    from repro.kernels import interpret_mode
+
+    def devices(*platforms):
+        return [SimpleNamespace(platform=p) for p in platforms]
+
+    assert interpret_mode(devices("tpu", "tpu", "tpu", "tpu")) is False
+    assert interpret_mode(devices("cpu")) is True
+    assert interpret_mode() is (jax.default_backend() == "cpu")
+    for bad in (devices("gpu"), devices("cpu", "tpu")):
+        with pytest.raises(ValueError, match="compiled on TPU"):
+            interpret_mode(bad)
 
 
 def test_ell_spmm_batches():

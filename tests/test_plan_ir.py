@@ -52,6 +52,17 @@ def test_ir_is_json_stable():
     assert ir["ir_version"] == IR_VERSION
 
 
+def test_ir_carries_no_interpret_flag():
+    """Interpret mode follows the devices a plan is loaded on, so the IR
+    does not carry it, and an IR that does (older writers) still loads."""
+    sm = _sm()
+    ir = sm.plan(impl="pallas", fmt="bcoo").to_ir()
+    assert "interpret" not in ir
+    x = np.random.default_rng(1).standard_normal(sm.shape[1]).astype(np.float32)
+    got = plan_from_ir({**ir, "interpret": False}, sm).compile()(x)
+    np.testing.assert_allclose(got, sm.dense() @ x, rtol=1e-5, atol=1e-5)
+
+
 def test_measured_metadata_rides_the_ir():
     sm = _sm()
     p = sm.plan()

@@ -1,5 +1,6 @@
 """Roofline machinery: HLO collective parser, extrapolation, analytic FLOPs."""
 import numpy as np
+import pytest
 
 from repro.analysis import roofline as R
 from repro.configs import get_config
@@ -62,12 +63,20 @@ def test_moe_uses_active_params():
 def test_roofline_report_fields():
     cfg = get_config("smollm-360m")
     terms = R.CostTerms(flops=1e12, bytes_hbm=1e8, coll_bytes=1e8)
-    rep = R.roofline_report(cfg, "train_4k", 256, terms)
+    rep = R.roofline_report(cfg, "train_4k", 256, terms, "TPU v5 lite")
     for k in ("compute_s", "memory_s", "collective_s", "dominant",
               "model_flops", "useful_ratio", "roofline_fraction"):
         assert k in rep
     assert rep["dominant"] == "compute_s"
     assert rep["roofline_fraction"] > 0  # synthetic terms: no upper bound
+
+
+def test_peaks_refuse_an_unknown_device_kind():
+    assert R.peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    cfg = get_config("smollm-360m")
+    terms = R.CostTerms(flops=1e12, bytes_hbm=1e8, coll_bytes=1e8)
+    with pytest.raises(KeyError, match="no published peaks"):
+        R.roofline_report(cfg, "train_4k", 256, terms, "cpu")
 
 
 def test_slstm_correction_only_for_slstm():
