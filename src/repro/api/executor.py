@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding
 from repro.core import distributed as D
 from repro.core.partition import PartitionedMatrix
 from repro.kernels import ops
+from repro.obs import NULL_TRACE
 
 from .iterate import IterateResult, run_iterate
 
@@ -59,7 +60,8 @@ class Executor:
 
     def iterate(self, x0, steps=None, tol=None, combine="plain", *,
                 b=None, diag=None, omega: float = 1.0,
-                max_steps: int = 1000, check_every: int = 8) -> IterateResult:
+                max_steps: int = 1000, check_every: int = 8,
+                trace=NULL_TRACE) -> IterateResult:
         """Run a compiled solver loop of SpMVs with x resident on device.
 
         One ``lax.scan`` (``steps=k``) or ``lax.while_loop`` (``tol=...``,
@@ -69,7 +71,10 @@ class Executor:
         callable ``f(x, y) -> x_next``) — see :mod:`repro.api.iterate`.
         Requires a square matrix.  The compiled loop is cached per
         (combine, mode), so repeated solves — including with new ``b`` —
-        pay no re-trace.
+        pay no re-trace.  The session's phases (``spmv.solve.place`` /
+        ``.loop`` / ``.fetch``) are profiler annotations and, on ``trace``,
+        the spans ``load`` / ``kernel`` / ``retrieve``
+        (:func:`repro.obs.phase`).
 
         Returns:
           :class:`IterateResult` — x on host, steps executed, convergence
@@ -84,7 +89,7 @@ class Executor:
         return run_iterate(
             self, self._iterate_apply(), x0, steps=steps, tol=tol,
             combine=combine, b=b, diag=diag, omega=omega,
-            max_steps=max_steps, check_every=check_every,
+            max_steps=max_steps, check_every=check_every, trace=trace,
         )
 
     def _iterate_shape(self):

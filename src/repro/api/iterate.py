@@ -31,13 +31,14 @@ so re-solving with a new right-hand side re-runs the same executable.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 from typing import Callable, Optional, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.obs import NULL_TRACE, phase
 
 __all__ = ["IterateResult", "Combine", "make_combine", "run_iterate",
            "COMBINES"]
@@ -59,7 +60,7 @@ class IterateResult:
     steps: int  # SpMV steps actually executed on device
     converged: bool  # tol given and final residual <= tol
     residual: float  # final residual (combine-specific norm)
-    load_s: float  # place x0 (+ b/diag params) on device
+    load_s: float  # place x0, b/diag and the scalars; ends once on device
     kernel_s: float  # the compiled solver loop
     retrieve_s: float  # fetch x + scalars back to host
     compiled: bool = False  # this call built+compiled the loop (cold start)
@@ -260,6 +261,7 @@ def run_iterate(
     omega: float = 1.0,
     max_steps: int = 1000,
     check_every: int = 8,
+    trace=NULL_TRACE,
 ) -> IterateResult:
     """Drive ``apply`` (device y = A @ v) as a compiled solver loop.
 
@@ -286,10 +288,15 @@ def run_iterate(
       omega: richardson damping factor.
       max_steps: tol-mode step bound — the never-hang guard.
       check_every: tol-mode steps between residual checks.
+      trace: a :class:`repro.obs.Trace` (or a sequence of them) on which
+        the phases ``spmv.solve.place`` / ``.loop`` / ``.fetch`` are
+        stamped as the request-lifecycle spans ``load`` / ``kernel`` /
+        ``retrieve``; each phase is also a profiler annotation of its own
+        name (:func:`repro.obs.phase`).
 
     Returns:
       :class:`IterateResult` (x on host, steps executed, convergence,
-      per-phase seconds).
+      per-phase seconds from the phases' boundaries).
 
     Raises:
       ValueError: for both/neither of steps and tol, a non-square executor
@@ -302,47 +309,47 @@ def run_iterate(
     if tol is not None and (tol <= 0 or max_steps < 1 or check_every < 1):
         raise ValueError("tol mode needs tol > 0, max_steps >= 1 and "
                          "check_every >= 1")
-    n, dtype = executor._iterate_shape()
-    x0 = executor._check_x(x0, n, dtype)
-    if x0.ndim != 1:
-        raise ValueError(f"iterate takes a single (n,) start vector; "
-                         f"got shape {x0.shape}")
-    comb = make_combine(combine)
+    with phase("spmv.solve.place", trace, span="load") as place:
+        n, dtype = executor._iterate_shape()
+        x0 = executor._check_x(x0, n, dtype)
+        if x0.ndim != 1:
+            raise ValueError(f"iterate takes a single (n,) start vector; "
+                             f"got shape {x0.shape}")
+        comb = make_combine(combine)
+        params = _build_params(comb, n, dtype, b, diag, omega)
+        params["tol"] = jnp.asarray(0.0 if tol is None else float(tol), dtype)
+        x0_dev = jnp.asarray(x0)
+        # the phase ends with its transfers, so they are not the loop's
+        jax.block_until_ready((x0_dev, params))
 
-    t0 = time.perf_counter()
-    params = _build_params(comb, n, dtype, b, diag, omega)
-    params["tol"] = jnp.asarray(0.0 if tol is None else float(tol), dtype)
-    x0_dev = jnp.asarray(x0)
-    t1 = time.perf_counter()
+    with phase("spmv.solve.loop", trace, span="kernel") as run:
+        cache = getattr(executor, "_iterate_loops", None)
+        if cache is None:
+            cache = executor._iterate_loops = {}
+        mode = ("steps", steps) if steps is not None else \
+            ("tol", max_steps, check_every)
+        key = (_combine_key(combine), mode)
+        loop = cache.get(key)
+        cold = loop is None
+        if cold:
+            loop = _build_loop(comb, apply, steps, max_steps, check_every)
+            cache[key] = loop
+        carry, k = loop(x0_dev, params)
+        x = carry["x"].block_until_ready()
 
-    cache = getattr(executor, "_iterate_loops", None)
-    if cache is None:
-        cache = executor._iterate_loops = {}
-    mode = ("steps", steps) if steps is not None else \
-        ("tol", max_steps, check_every)
-    key = (_combine_key(combine), mode)
-    loop = cache.get(key)
-    cold = loop is None
-    if cold:
-        loop = _build_loop(comb, apply, steps, max_steps, check_every)
-        cache[key] = loop
-
-    carry, k = loop(x0_dev, params)
-    x = carry["x"].block_until_ready()
-    t2 = time.perf_counter()
-    steps_run = int(k)
-    residual = float(carry["res"])
-    x_host = np.asarray(x)
-    t3 = time.perf_counter()
+    with phase("spmv.solve.fetch", trace, span="retrieve") as fetch:
+        steps_run = int(k)
+        residual = float(carry["res"])
+        x_host = np.asarray(x)
 
     return IterateResult(
         x=x_host,
         steps=steps_run,
         converged=bool(tol is not None and residual <= tol),
         residual=residual,
-        load_s=t1 - t0,
-        kernel_s=t2 - t1,
-        retrieve_s=t3 - t2,
+        load_s=place.seconds,
+        kernel_s=run.seconds,
+        retrieve_s=fetch.seconds,
         compiled=cold,
     )
 
@@ -352,8 +359,10 @@ def _build_loop(comb: Combine, apply: Callable, steps: Optional[int],
     """Compile the solver loop: (x0_dev, params) -> (carry, steps_run)."""
 
     def one_step(carry, params):
-        y = apply(comb.vector(carry))
-        return comb.step(carry, y, params)
+        # names the step's ops in a device profile (metadata only)
+        with jax.named_scope("iterate.step"):
+            y = apply(comb.vector(carry))
+            return comb.step(carry, y, params)
 
     if steps is not None:
 

@@ -447,7 +447,9 @@ class ExecutionPlan:
 
     # -- compilation -------------------------------------------------------
 
-    def _partition(self) -> PartitionedMatrix:
+    def partition(self) -> PartitionedMatrix:
+        """The host partition of the matrix: ``part`` where the plan holds
+        one, else partitioned from the dense matrix."""
         if self.part is not None:
             return self.part
         a = self.matrix.dense()
@@ -491,7 +493,7 @@ class ExecutionPlan:
         if not self.is_distributed:
             raise ValueError("single-device plans have no shard_map program; "
                              "call .compile() instead")
-        return self._program(part if part is not None else self._partition())
+        return self._program(part if part is not None else self.partition())
 
     def compile(self) -> Executor:
         """Partition (if needed), place and trace — returns the Executor.
@@ -511,7 +513,7 @@ class ExecutionPlan:
                                               dtype=self.dtype)
             return SingleDeviceExecutor(self, container, self.impl)
         t0 = _time.perf_counter()
-        part = self._partition()
+        part = self.partition()
         axes = self.axes
         program = self._program(part)
         extra = self._pallas_extra(part)

@@ -38,6 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.partition import PartitionedMatrix
 from repro.kernels import ref as kref
+from repro.kernels.ops import kernel_scope
 
 __all__ = [
     "SpmvOutput",
@@ -134,8 +135,19 @@ def _local_kernel(mat: PartitionedMatrix, impl: str, mesh):
     ``chunk_*``), the block kernel for BCSR/BCOO.  Both impls return the
     values dtype (accumulation happens wider inside, matching the oracle
     contract the merge collectives rely on).  The Pallas kernels compile
-    for a TPU mesh and run in interpret mode on a CPU mesh.
+    for a TPU mesh and run in interpret mode on a CPU mesh.  The kernel's
+    ops sit under :func:`kernel_scope`.
     """
+    local = _tile_kernel(mat, impl, mesh)
+
+    def named(sl, x_local):
+        with kernel_scope(mat.fmt, impl):
+            return local(sl, x_local)
+
+    return named
+
+
+def _tile_kernel(mat: PartitionedMatrix, impl: str, mesh):
     if impl == "xla":
         return lambda sl, x_local: _local_spmv(mat, sl, x_local)
     if impl != "pallas":
@@ -421,18 +433,20 @@ def spmv_1d_ring(
         def body(carry, s):
             y, xbuf = carry
             holder = (me + s) % Pn  # shard id currently in xbuf
-            start = holder * cap_b
-            br = jax.lax.dynamic_slice_in_dim(sl["rowind"], start, cap_b)
-            bc = jax.lax.dynamic_slice_in_dim(sl["colind"], start, cap_b)
-            bv = jax.lax.dynamic_slice_in_dim(sl["values"], start, cap_b)
-            valid = barange < jnp.take(my_counts, holder)
-            local_col = bc - holder * shard_w
-            acc = y.dtype
-            xv = jnp.take(xbuf, jnp.clip(local_col, 0, shard_w - 1),
-                          axis=0).astype(acc)
-            prod = bv.astype(acc)[(...,) + (None,) * (xv.ndim - 1)] * xv
-            prod = jnp.where(valid[(...,) + (None,) * (prod.ndim - 1)], prod, 0)
-            y = y.at[br].add(prod, mode="drop")
+            with kernel_scope(mat.fmt, "xla"):
+                start = holder * cap_b
+                br = jax.lax.dynamic_slice_in_dim(sl["rowind"], start, cap_b)
+                bc = jax.lax.dynamic_slice_in_dim(sl["colind"], start, cap_b)
+                bv = jax.lax.dynamic_slice_in_dim(sl["values"], start, cap_b)
+                valid = barange < jnp.take(my_counts, holder)
+                local_col = bc - holder * shard_w
+                acc = y.dtype
+                xv = jnp.take(xbuf, jnp.clip(local_col, 0, shard_w - 1),
+                              axis=0).astype(acc)
+                prod = bv.astype(acc)[(...,) + (None,) * (xv.ndim - 1)] * xv
+                prod = jnp.where(valid[(...,) + (None,) * (prod.ndim - 1)],
+                                 prod, 0)
+                y = y.at[br].add(prod, mode="drop")
             xbuf = jax.lax.ppermute(xbuf, axis, perm)
             return (y, xbuf), None
 

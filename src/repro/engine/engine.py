@@ -18,7 +18,6 @@ same ``repro.api.fit_plan`` rules every other entry point uses.
 from __future__ import annotations
 
 import threading
-import time
 from typing import Optional, Tuple
 
 import numpy as np
@@ -31,12 +30,21 @@ from repro.engine.plan_cache import CompiledPlan, PlanCache, PlanKey
 from repro.engine.registry import MatrixRegistry, RegisteredMatrix
 from repro.engine.telemetry import RequestRecord, Telemetry
 from repro.kernels.mode import interpret_mode
-from repro.obs import profile as obs_profile
+from repro.obs import NULL_TRACE, phase
 
 __all__ = ["SpmvEngine"]
 
 _AXIS_1D = AXIS_1D
 _AXES_2D = AXES_2D
+
+
+class _PhaseSeconds(dict):
+    """A Trace-shaped sink for :func:`repro.obs.phase`: keeps each phase's
+    seconds under the last part of its name (``spmv.register.plan`` ->
+    ``plan``)."""
+
+    def add(self, name: str, start_s: float, end_s: float, **args) -> None:
+        self[name.rsplit(".", 1)[1]] = end_s - start_s
 
 
 class SpmvEngine:
@@ -171,40 +179,44 @@ class SpmvEngine:
             if entry.cache_key == compiled.key:
                 entry.spill = compiled.part
 
+    def _plan(self, sm: SparseMatrix, plan: Plan, impl: str, assignment):
+        """The ExecutionPlan of ``plan`` on this engine's pool."""
+        if self.topology is not None:
+            # let plan() place the mesh by collective cost (device order
+            # follows the cheapest axis assignment; docs/topology.md)
+            return sm.plan(
+                scheme=plan, devices=self.devices, topology=self.topology,
+                impl=impl, block=self.block, hw=self.hw,
+                assignment=assignment,
+            )
+        if plan.partitioning == "1d":
+            mesh = self._mesh((plan.grid[0],), (_AXIS_1D,))
+        else:
+            mesh = self._mesh(tuple(plan.grid), _AXES_2D)
+        return sm.plan(scheme=plan, mesh=mesh, impl=impl, block=self.block,
+                       hw=self.hw)
+
     def _build(self, sm: SparseMatrix, plan: Plan, key: PlanKey,
-               impl: str, part=None, assignment=None) -> CompiledPlan:
+               impl: str, part=None, assignment=None, trace=NULL_TRACE,
+               scope: str = "spmv.compile") -> CompiledPlan:
         """Run the api chain once for ``plan`` and wrap the MeshExecutor.
 
         ``part`` short-circuits host partitioning with a spilled
         PartitionedMatrix (reactivation after eviction): the build then
         only re-places and re-traces.  ``assignment`` pins a measured axis
-        assignment (tuned winners) instead of the cost model's pick.
+        assignment (tuned winners) instead of the cost model's pick.  The
+        phases ``<scope>.partition`` (host partition) and ``<scope>.place``
+        (the matrix put on the mesh and the program built; the transfer
+        may still run after it) are recorded on ``trace``.
         """
-        t0 = time.perf_counter()
-        if self.topology is not None:
-            # let plan() place the mesh by collective cost (device order
-            # follows the cheapest axis assignment; docs/topology.md)
-            ep = sm.plan(
-                scheme=plan, devices=self.devices, topology=self.topology,
-                impl=impl, block=self.block, hw=self.hw,
-                assignment=assignment,
-            )
-        else:
-            if plan.partitioning == "1d":
-                mesh = self._mesh((plan.grid[0],), (_AXIS_1D,))
+        with phase(f"{scope}.partition", trace) as part_phase:
+            ep = self._plan(sm, plan, impl, assignment)
+            if part is not None:
+                ep.part = part  # spilled host partition: skip re-partitioning
             else:
-                mesh = self._mesh(tuple(plan.grid), _AXES_2D)
-            ep = sm.plan(
-                scheme=plan, mesh=mesh, impl=impl, block=self.block,
-                hw=self.hw,
-            )
-        if part is not None:
-            ep.part = part  # spilled host partition: skip re-partitioning
-        else:
-            self.partition_count += 1
-        # label the (expensive) partition+place+trace region in any captured
-        # device profile; a no-op wherever jax.profiler is unavailable
-        with obs_profile.annotate(f"plan_compile:{plan.tag}:{impl}"):
+                ep.part = ep.partition()
+                self.partition_count += 1
+        with phase(f"{scope}.place", trace) as place_phase:
             exe = ep.compile()
         return CompiledPlan(
             key=key,
@@ -218,7 +230,7 @@ class SpmvEngine:
             x_spec=exe.x_spec,
             x_pad=exe.x_pad,
             trace_count_fn=exe.trace_count_fn,
-            build_seconds=time.perf_counter() - t0,
+            build_seconds=place_phase.end_s - part_phase.start_s,
             assemble_meta=exe.assemble_meta,
             executor=exe,
         )
@@ -259,53 +271,51 @@ class SpmvEngine:
             runs the lane-tiled Pallas kernels end to end.
 
         Returns:
-          The RegisteredMatrix registry entry.
+          The RegisteredMatrix registry entry; its ``register_s`` holds the
+          seconds of each phase that ran (the ``spmv.register.<phase>``
+          annotations: stats, fingerprint, plan, partition, place, compile).
 
         Raises:
           ValueError: for a non-2D matrix, an unknown ``impl``, or ``a=None``
             without a prior registration holding the host-side matrix.
         """
+        seconds = _PhaseSeconds()
+        with phase("spmv.register", matrix=name):
+            entry = self._register(name, a, dtype, plan, partitioning, warmup,
+                                   impl, seconds)
+        entry.register_s = dict(seconds)
+        return entry
+
+    def _register(self, name, a, dtype, plan, partitioning, warmup, impl,
+                  seconds: _PhaseSeconds) -> RegisteredMatrix:
         prior = self.registry.find(name)
-        if a is None:
-            if prior is None or prior.matrix is None:
-                raise ValueError(
-                    f"register({name!r}) without a matrix needs a prior "
-                    "registration holding its host-side SparseMatrix"
-                )
-            sm = prior.matrix
-            if dtype is not None and np.dtype(dtype) != sm.dtype:
-                sm = SparseMatrix.from_dense(
-                    sm.dense().astype(dtype), stats_block=self.block
-                )
-        else:
-            a = np.asarray(a)
-            if dtype is not None:
-                a = a.astype(dtype)
-            if a.ndim != 2:
-                raise ValueError(f"expected a 2D matrix, got shape {a.shape}")
-            sm = SparseMatrix.from_dense(a, stats_block=self.block)
-        impl = self.impl if impl is None else impl
-        if impl not in ("xla", "pallas"):
-            raise ValueError(f"unknown impl {impl!r}: 'xla' or 'pallas'")
-        plan = resolve_scheme(
-            sm.stats, sm.shape, self.n_devices,
-            plan if plan is not None else "auto",
-            hw=self.hw, partitioning=partitioning, block=self.block,
-            compiled_pallas=(impl == "pallas"
-                             and not interpret_mode(self.devices)),
-        )
-        fp = sm.fingerprint()
-        scheme_id = plan.tag
-        key: PlanKey = (fp, tuple(plan.grid), sm.dtype.str, scheme_id,
-                        impl)
-        with self._swap_lock:
-            compiled = self.cache.get(key)
+        with phase("spmv.register.stats", seconds):
+            sm = self._host_matrix(name, a, dtype, prior)
+            impl = self.impl if impl is None else impl
+            if impl not in ("xla", "pallas"):
+                raise ValueError(f"unknown impl {impl!r}: 'xla' or 'pallas'")
+            stats = sm.stats
+        with phase("spmv.register.fingerprint", seconds):
+            fp = sm.fingerprint()
+        with phase("spmv.register.plan", seconds):
+            plan = resolve_scheme(
+                stats, sm.shape, self.n_devices,
+                plan if plan is not None else "auto",
+                hw=self.hw, partitioning=partitioning, block=self.block,
+                compiled_pallas=(impl == "pallas"
+                                 and not interpret_mode(self.devices)),
+            )
+            key: PlanKey = (fp, tuple(plan.grid), sm.dtype.str, plan.tag,
+                            impl)
+            with self._swap_lock:
+                compiled = self.cache.get(key)
         if compiled is None:
             # an eviction-spilled partition for this exact plan identity
             # short-circuits host partitioning
             part = (prior.spill
                     if prior is not None and prior.cache_key == key else None)
-            compiled = self._build(sm, plan, key, impl, part=part)
+            compiled = self._build(sm, plan, key, impl, part=part,
+                                   trace=seconds, scope="spmv.register")
             with self._swap_lock:
                 self.cache.put(compiled)
         entry = RegisteredMatrix(
@@ -313,7 +323,7 @@ class SpmvEngine:
             fingerprint=fp,
             shape=sm.shape,
             dtype=sm.dtype.str,
-            stats=sm.stats,
+            stats=stats,
             plan=compiled.plan,
             cache_key=key,
             matrix=sm,  # host-side; lets the tuner + reactivation re-plan
@@ -326,8 +336,31 @@ class SpmvEngine:
             with self._swap_lock:
                 self.cache.evict(prior.cache_key)
         if warmup:
-            compiled.executor.warmup()
+            with phase("spmv.register.compile", seconds):
+                compiled.executor.warmup()
         return entry
+
+    def _host_matrix(self, name, a, dtype, prior) -> SparseMatrix:
+        """The host-side SparseMatrix to register: built from ``a``, or the
+        one a prior registration of ``name`` holds."""
+        if a is None:
+            if prior is None or prior.matrix is None:
+                raise ValueError(
+                    f"register({name!r}) without a matrix needs a prior "
+                    "registration holding its host-side SparseMatrix"
+                )
+            sm = prior.matrix
+            if dtype is not None and np.dtype(dtype) != sm.dtype:
+                sm = SparseMatrix.from_dense(
+                    sm.dense().astype(dtype), stats_block=self.block
+                )
+            return sm
+        a = np.asarray(a)
+        if dtype is not None:
+            a = a.astype(dtype)
+        if a.ndim != 2:
+            raise ValueError(f"expected a 2D matrix, got shape {a.shape}")
+        return SparseMatrix.from_dense(a, stats_block=self.block)
 
     def _compiled(self, entry: RegisteredMatrix) -> CompiledPlan:
         # lock: the background refine thread mutates the cache (put can
@@ -372,17 +405,19 @@ class SpmvEngine:
                 f"{name!r} carries no host-side SparseMatrix to reactivate "
                 "from; re-register it with the dense matrix"
             )
-        built = self._build(entry.matrix, entry.plan, entry.cache_key,
-                            entry.cache_key[4], part=entry.spill)
-        with self._swap_lock:
-            if self.cache.peek(entry.cache_key) is not None:
-                built.release()  # lost a race; the cached build wins
-                self.cache.get(entry.cache_key)
-            else:
-                self.cache.put(built)
-        entry.spill = None  # the live CompiledPlan owns the partition again
-        if warmup:
-            self.plan_for(name).executor.warmup()
+        with phase("spmv.compile", matrix=name):
+            built = self._build(entry.matrix, entry.plan, entry.cache_key,
+                                entry.cache_key[4], part=entry.spill)
+            with self._swap_lock:
+                if self.cache.peek(entry.cache_key) is not None:
+                    built.release()  # lost a race; the cached build wins
+                    self.cache.get(entry.cache_key)
+                else:
+                    self.cache.put(built)
+            entry.spill = None  # the live CompiledPlan owns the partition
+            if warmup:
+                with phase("spmv.compile.warm"):
+                    self.plan_for(name).executor.warmup()
         return entry
 
     def multiply(self, name: str, x, *, obs=None) -> np.ndarray:
@@ -397,9 +432,11 @@ class SpmvEngine:
           x: (cols,) vector, or (cols, B) for a batched SpMM request.
           obs: optional :class:`repro.obs.Trace` handle — or a sequence of
             them, one per rider of a coalesced batch — on which the three
-            phase spans (load/kernel/retrieve) of THIS execution are
-            recorded.  Riders share the batch's phase timestamps: the batch
-            ran once, and that once is each rider's kernel time.
+            request-lifecycle spans (load/kernel/retrieve) of THIS execution
+            are recorded: the program phases ``spmv.multiply.place`` /
+            ``.run`` / ``.fetch`` stamp them.  Riders share
+            the batch's phase timestamps: the batch ran once, and that once
+            is each rider's kernel time.
 
         Returns:
           Host rows (rows[, B]).
@@ -416,21 +453,17 @@ class SpmvEngine:
         batch = x.shape[1] if x.ndim == 2 else 1
 
         traces_before = cp.trace_count
-        t0 = time.perf_counter()
-        with obs_profile.annotate(f"spmv_load:{name}"):
-            xs = exe.place(x)  # load: validate dtype/shape, pad, put on mesh
-        t1 = time.perf_counter()
-        with obs_profile.annotate(f"spmv_kernel:{name}:b{batch}"):
-            raw = exe.run_raw(xs)  # kernel: the cached jitted shard_map program
-        t2 = time.perf_counter()
-        with obs_profile.annotate(f"spmv_retrieve:{name}"):
-            y = exe.assemble(raw)  # retrieve: fetch + assemble global rows
-        t3 = time.perf_counter()
-        if obs is not None:
-            for ctx in (obs if isinstance(obs, (list, tuple)) else (obs,)):
-                ctx.add("load", t0, t1)
-                ctx.add("kernel", t1, t2, batch=batch)
-                ctx.add("retrieve", t2, t3)
+        with phase("spmv.multiply", matrix=name, batch=batch):
+            # load: validate dtype/shape, pad, put on mesh
+            with phase("spmv.multiply.place", obs, span="load") as load:
+                xs = exe.place(x)
+            # kernel: the cached jitted shard_map program
+            with phase("spmv.multiply.run", obs, span="kernel",
+                       batch=batch) as run:
+                raw = exe.run_raw(xs)
+            # retrieve: fetch + assemble global rows
+            with phase("spmv.multiply.fetch", obs, span="retrieve") as fetch:
+                y = exe.assemble(raw)
 
         entry.requests += batch
         warm = cp.requests_served > 0
@@ -438,9 +471,9 @@ class SpmvEngine:
         self.telemetry.record(RequestRecord(
             name=name,
             batch=batch,
-            load_s=t1 - t0,
-            kernel_s=t2 - t1,
-            retrieve_s=t3 - t2,
+            load_s=load.seconds,
+            kernel_s=run.seconds,
+            retrieve_s=fetch.seconds,
             cache_hit=warm,
             traced=cp.trace_count > traces_before,
         ))
@@ -489,8 +522,9 @@ class SpmvEngine:
           steps / tol / combine / b / diag / omega / max_steps /
             check_every: forwarded to ``Executor.iterate``.
           obs: optional :class:`repro.obs.Trace` — the session's
-            load / kernel / retrieve spans are recorded on it (kernel is the
-            whole loop; ``steps`` rides as a span attribute).
+            load / kernel / retrieve lifecycle spans are recorded on it by
+            the phases ``spmv.solve.place`` / ``.loop`` / ``.fetch`` (kernel
+            is the whole loop).
 
         Returns:
           :class:`repro.api.IterateResult`.
@@ -509,19 +543,12 @@ class SpmvEngine:
             self.reactivate(name, warmup=False)
             cp = self._compiled(entry)
         traces_before = cp.trace_count
-        t0 = time.perf_counter()
-        with obs_profile.annotate(f"spmv_solve:{name}"):
+        with phase("spmv.solve", matrix=name):
             result = cp.executor.iterate(
                 x0, steps=steps, tol=tol, combine=combine, b=b, diag=diag,
                 omega=omega, max_steps=max_steps, check_every=check_every,
+                trace=obs,
             )
-        if obs is not None:
-            t1 = t0 + result.load_s
-            t2 = t1 + result.kernel_s
-            for ctx in (obs if isinstance(obs, (list, tuple)) else (obs,)):
-                ctx.add("load", t0, t1)
-                ctx.add("kernel", t1, t2, steps=result.steps)
-                ctx.add("retrieve", t2, t2 + result.retrieve_s)
         entry.requests += result.steps  # a session is `steps` SpMVs of traffic
         warm = cp.requests_served > 0
         cp.requests_served += 1

@@ -9,7 +9,7 @@ edit — even one value — is a miss.
 """
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, Iterator, Optional
 
 from repro.api.matrix import fingerprint_matrix  # canonical implementation
@@ -44,6 +44,9 @@ class RegisteredMatrix:
     # measured at (the drift re-tune reference point)
     batch_ewma: Optional[float] = None  # EWMA of served batch widths; when
     # it drifts drift_factor x away from tuned_batch, the engine re-tunes
+    register_s: dict = field(default_factory=dict)  # seconds of each
+    # register phase that ran (stats, fingerprint, plan, partition, place,
+    # compile), from the spmv.register.<phase> spans' boundaries
 
     def summary(self) -> dict:
         """JSON-safe identity + serving state — what crosses a process

@@ -25,7 +25,14 @@ from .csr_spmv import csr_plan_chunks, csr_spmv_pallas
 from .ell_spmv import ell_spmv_pallas
 
 __all__ = ["spmv", "spmm", "pallas_program", "spmv_local_coo",
-           "spmv_local_block", "ell_spmv_pallas"]
+           "spmv_local_block", "ell_spmv_pallas", "kernel_scope"]
+
+
+def kernel_scope(fmt: str, impl: str):
+    """``jax.named_scope("spmv.<fmt>.<impl>")``, ``spmv.coo.xla``: the name a
+    device profile gives a local SpMV kernel's ops (metadata only; the
+    compiled program does not change)."""
+    return jax.named_scope(f"spmv.{fmt}.{impl}")
 
 
 def _require_concrete(m) -> None:
@@ -52,8 +59,14 @@ def spmv(m, x: jax.Array, impl: str = "xla") -> jax.Array:
     For ``impl="pallas"`` on the scalar formats the (static) chunk plan is
     built host-side from concrete index arrays — matrices are preprocessing
     artifacts (paper §3.1 excludes matrix load/plan time), so `m` must hold
-    concrete arrays in that mode.
+    concrete arrays in that mode.  The kernel's ops sit under
+    :func:`kernel_scope`, as on a mesh.
     """
+    with kernel_scope(type(m).__name__.lower(), impl):
+        return _spmv(m, x, impl)
+
+
+def _spmv(m, x, impl):
     if impl == "xla":
         if isinstance(m, F.CSR):
             return ref.csr_spmv_ref(m.rowptr, m.colind, m.values, x, m.rows)

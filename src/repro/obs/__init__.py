@@ -13,10 +13,12 @@ path, so a single trace shows where a request's deadline went:
   * :mod:`metrics` — ``MetricsRegistry``: counters, gauges and windowed
     p50/p95/p99 histograms for queue depth, batch width, tokens remaining,
     cache hit/miss, shed-by-reason and per-phase latency series.
-  * :mod:`profile` — guarded ``jax.profiler`` annotation wrappers
-    (``annotate`` / ``step_annotate``) that label plan compiles and kernel
-    dispatches inside an externally captured device profile, and degrade
-    to no-ops wherever the profiler is absent.
+  * :mod:`profile` — ``phase``: the one context manager the program
+    times its phases with (``spmv.solve.place``, ``spmv.multiply.run``,
+    ...), written both as a span on a ``Trace`` and as a ``jax.profiler``
+    annotation of the same name, so a captured device profile shows the
+    program's phases on the device's clock; annotations degrade to no-ops
+    wherever the profiler is absent.
 
 Wiring: `repro.serve.AsyncSpmvService` owns a ``Tracer`` + ``MetricsRegistry``
 and threads a per-request trace through `repro.engine.MicroBatcher` into
@@ -26,7 +28,7 @@ renders a replay as one Perfetto-loadable artifact.  See
 ``docs/observability.md``.
 """
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
-from .profile import annotate, profiler_available, set_enabled, step_annotate
+from .profile import annotate, phase, set_enabled
 from .tracing import (
     NULL_TRACE,
     PHASES,
@@ -54,7 +56,6 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "annotate",
-    "step_annotate",
+    "phase",
     "set_enabled",
-    "profiler_available",
 ]

@@ -51,7 +51,7 @@ __all__ = [
 ]
 
 # Canonical request-lifecycle phase names, in timeline order.  Layers are
-# free to add others (e.g. "plan_compile"), but these are the ones the SLO
+# free to add others (e.g. "serve"), but these are the ones the SLO
 # attribution and the 5%-coverage contract are defined over.
 PHASES = (
     "admit",

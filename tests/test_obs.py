@@ -232,17 +232,21 @@ def test_snapshot_rendering():
 def test_profile_annotations_degrade_to_noop():
     was = obs_profile.set_enabled(True)
     try:
-        with obs_profile.annotate("spmv_kernel:reg:b4"):
+        with obs_profile.annotate("spmv.multiply.run", matrix="reg"):
             pass
-        with obs_profile.step_annotate("batch", step=3):
+        with obs_profile.phase("spmv.multiply.run", matrix="reg") as p:
             pass
+        assert p._annotation is not obs_profile._NULL and p.seconds >= 0
         assert obs_profile.set_enabled(False) is False
         # disabled: the SAME shared no-op object, never a per-call allocation
         a = obs_profile.annotate("x")
-        assert a is obs_profile.annotate("y")
-        assert a is obs_profile.step_annotate("z", step=1)
+        assert a is obs_profile.annotate("y", matrix="reg")
         with a:
             pass
+        # a phase is still timed, behind the shared no-op annotation
+        with obs_profile.phase("spmv.solve.loop") as p:
+            pass
+        assert p._annotation is a and p.end_s >= p.start_s > 0
     finally:
         obs_profile.set_enabled(was)
 

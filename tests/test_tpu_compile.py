@@ -98,7 +98,7 @@ def test_2d_program_compiles_on_the_mesh(topo, block_sm, fmt, impl):
     plan = block_sm.plan(scheme="2d.equally-sized", fmt=fmt, impl=impl,
                          topology=placement)
     assert plan.grid == (2, 2) and plan.fmt == fmt
-    part = plan._partition()
+    part = plan.partition()
     arrs = D._arrays(part)
     R, C = part.grid
     tiles = NamedSharding(plan.mesh, P(*plan.axes))
