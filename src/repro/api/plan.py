@@ -275,6 +275,9 @@ class ExecutionPlan:
     # {"logical": [...], "physical": [[...], ...], "topology": name,
     #  "transfer": {"load_s", "merge_s", "total_s"}}
     topo_assignment: Optional[dict] = None
+    # the XLA scalar tile kernel's row-slice layout, set by compile():
+    # {"width", "padded_share", "merged"} (repro.kernels.row_slices)
+    row_slices: Optional[dict] = None
 
     # -- inspection --------------------------------------------------------
 
@@ -361,6 +364,11 @@ class ExecutionPlan:
                 line += (f"; analytic pick {m.get('baseline_scheme_id')} "
                          f"measured {base:.2e}s ({m.get('speedup', 1.0):.2f}x)")
             lines.append(line)
+        if self.row_slices:
+            rs = self.row_slices
+            lines.append(f"  row slices: w={rs['width']} "
+                         f"padded={rs['padded_share']:.2%} "
+                         f"merged={rs['merged']}/SpMV")
         return "\n".join(lines)
 
     # -- serialization (plan IR) -------------------------------------------
@@ -476,12 +484,19 @@ class ExecutionPlan:
         return D.spmv_2d(part, self.mesh, axes, merge=self.merge,
                          impl=self.impl)
 
-    def _pallas_extra(self, part: PartitionedMatrix) -> Optional[dict]:
-        """Host chunk-plan arrays to place with the matrix (Pallas scalar
-        formats only; block formats run on the partition arrays as-is)."""
-        if self.impl == "pallas" and not self.ring and self.fmt in ("coo", "csr"):
+    def placed_extra(self, part: PartitionedMatrix) -> Optional[dict]:
+        """Host arrays of the tile kernel's layout, placed with the matrix:
+        the row slices (impl="xla") or chunk plans (impl="pallas") of the
+        scalar formats.  Block formats and the ring schedule run on the
+        partition arrays as-is.  Records the row-slice counts in
+        ``row_slices``."""
+        if self.ring or self.fmt not in ("coo", "csr"):
+            return None
+        if self.impl == "pallas":
             return D.pallas_chunk_arrays(part)
-        return None
+        layout = D.slice_arrays(part)
+        self.row_slices = layout.summary()
+        return layout.arrays
 
     def program(self, part: Optional[PartitionedMatrix] = None):
         """Build the shard_map call object (with ``.jitted``) WITHOUT placing
@@ -502,9 +517,9 @@ class ExecutionPlan:
         :class:`~repro.api.executor.SingleDeviceExecutor` (for impl="pallas"
         the host-side kernel plan is built here, once).  Distributed plans
         partition, build the shard_map program with the selected local tile
-        kernel (XLA oracles or Pallas), place the matrix — plus, for Pallas
-        scalar formats, the per-shard chunk plans — and return a
-        :class:`~repro.api.executor.MeshExecutor`.
+        kernel (XLA or Pallas), place the matrix — plus, for the scalar
+        formats, the kernel's per-shard layout (:meth:`placed_extra`) — and
+        return a :class:`~repro.api.executor.MeshExecutor`.
         """
         import time as _time
 
@@ -516,7 +531,7 @@ class ExecutionPlan:
         part = self.partition()
         axes = self.axes
         program = self._program(part)
-        extra = self._pallas_extra(part)
+        extra = self.placed_extra(part)
         if self.partitioning == "1d":
             placed = D.place_1d(part, self.mesh, axes[0], extra=extra)
         else:

@@ -38,6 +38,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.partition import PartitionedMatrix
 from repro.kernels import ref as kref
+from repro.kernels import row_slices
 from repro.kernels.ops import kernel_scope
 
 __all__ = [
@@ -50,6 +51,7 @@ __all__ = [
     "assemble_rows",
     "bucket_by_source_shard",
     "pallas_chunk_arrays",
+    "slice_arrays",
 ]
 
 
@@ -63,17 +65,6 @@ class SpmvOutput:
     rows: int
     merge: str = "none"  # none | psum | psum_scatter | global
     replicated_global: jax.Array | None = None  # set by 2D merge="global"
-
-
-def _local_spmv(mat: PartitionedMatrix, sl, x_local: jax.Array) -> jax.Array:
-    """Dispatch the local tile kernel by format family (normal forms)."""
-    if mat.fmt in ("coo", "csr"):
-        return kref.coo_spmv_ref(
-            sl["rowind"], sl["colind"], sl["values"], x_local, mat.h_pad, nnz=sl["nnz"]
-        )
-    return kref.bcoo_spmv_ref(
-        sl["rowind"], sl["colind"], sl["values"], x_local, mat.h_pad, nblocks=sl["nnz"]
-    )
 
 
 def _pallas_span(h_pad: int) -> int:
@@ -125,10 +116,31 @@ def pallas_chunk_arrays(mat: PartitionedMatrix, chunk: int | None = None) -> dic
             if isinstance(v, np.ndarray)}
 
 
+def slice_arrays(mat: PartitionedMatrix) -> row_slices.SliceLayout:
+    """Host-side row-slice layout of a scalar-format partition, for the XLA
+    tile kernel (:mod:`repro.kernels.row_slices`).
+
+    Cuts each part's first ``nnz[p]`` entries into slices of one width,
+    chosen from the row-length histogram of the whole partition, with one
+    slice count ``S`` for every part.  The layout's ``arrays``
+    (``slice_colind`` / ``slice_values`` (P, w, S), ``slice_row`` (P, S)
+    unless every row is one slice) are placed with the matrix, like the
+    Pallas chunk plans; this runs once per compiled plan.
+    """
+    if mat.fmt not in ("coo", "csr"):
+        raise ValueError("row slices are for scalar formats; block formats "
+                         "run bcoo_spmv_ref on the partition arrays")
+    return row_slices.build_row_slices(
+        np.asarray(mat.rowind), np.asarray(mat.colind),
+        np.asarray(mat.values), np.asarray(mat.nnz), mat.h_pad)
+
+
 def _local_kernel(mat: PartitionedMatrix, impl: str, mesh):
     """Build the per-shard kernel ``f(sl, x_local) -> y (h_pad[, B])``.
 
-    impl="xla" dispatches the jnp oracles (lower everywhere, shard-safe);
+    impl="xla" runs the row-slice kernel for COO/CSR (its ``slice_*``
+    arrays, from :func:`slice_arrays`, carried in the placed arrays) and
+    the jnp block oracle for BCSR/BCOO (lower everywhere, shard-safe);
     impl="pallas" runs the TPU kernels on the local tile — the chunked
     windowed kernel for COO/CSR (plans prebuilt host-side by
     :func:`pallas_chunk_arrays` and carried in the placed arrays under
@@ -149,7 +161,13 @@ def _local_kernel(mat: PartitionedMatrix, impl: str, mesh):
 
 def _tile_kernel(mat: PartitionedMatrix, impl: str, mesh):
     if impl == "xla":
-        return lambda sl, x_local: _local_spmv(mat, sl, x_local)
+        if mat.fmt in ("coo", "csr"):
+            return lambda sl, x_local: row_slices.row_slice_spmv(
+                sl["slice_colind"], sl["slice_values"], x_local, mat.h_pad,
+                sl.get("slice_row"))
+        return lambda sl, x_local: kref.bcoo_spmv_ref(
+            sl["rowind"], sl["colind"], sl["values"], x_local, mat.h_pad,
+            nblocks=sl["nnz"])
     if impl != "pallas":
         raise ValueError(f"unknown impl {impl!r}: 'xla' or 'pallas'")
     from repro.kernels.mode import interpret_mode
@@ -213,7 +231,8 @@ def place_1d(mat: PartitionedMatrix, mesh, axis: str | tuple = "data",
     """Shard the part axis of a 1D partition over one (or more) mesh axes.
 
     ``extra`` merges additional host arrays with the same leading part axis
-    into the placed pytree (e.g. the Pallas ``chunk_*`` plan arrays).
+    into the placed pytree (the XLA ``slice_*`` or Pallas ``chunk_*``
+    arrays of the scalar formats).
     """
     spec = P(axis)
     arrs = _arrays(mat)
@@ -272,10 +291,10 @@ def spmv_1d(
     granular schemes need no merge; element-granular ('1d.nnz') corrects the
     single split row per boundary with one collective_permute.
 
-    ``impl`` selects the per-shard tile kernel (XLA oracles or the Pallas
-    kernels); for impl="pallas" on scalar formats the placed arrays must
-    include the ``chunk_*`` plan arrays (``pallas_chunk_arrays``) — pass
-    them as ``extra=`` to :func:`place_1d`.
+    ``impl`` selects the per-shard tile kernel (XLA or the Pallas kernels);
+    on scalar formats the placed arrays must include the kernel's layout —
+    ``slice_arrays(mat).arrays`` for impl="xla", ``pallas_chunk_arrays``
+    for impl="pallas" — passed as ``extra=`` to :func:`place_1d`.
     """
     Pn = mat.n_parts
     head_shared, next_shared, recv_pos = _boundary_meta(mat)
@@ -512,8 +531,8 @@ def spmv_2d(
                         path and its bottleneck (Obs. 12).
 
     ``impl`` selects the per-shard tile kernel exactly as in
-    :func:`spmv_1d` (Pallas scalar formats need the placed ``chunk_*``
-    arrays, via ``place_2d(..., extra=pallas_chunk_arrays(mat))``).
+    :func:`spmv_1d` (scalar formats need their layout placed with the
+    matrix, e.g. ``place_2d(..., extra=slice_arrays(mat).arrays)``).
     """
     R, C = mat.grid
     da, ma = axes

@@ -327,6 +327,7 @@ class SpmvEngine:
             plan=compiled.plan,
             cache_key=key,
             matrix=sm,  # host-side; lets the tuner + reactivation re-plan
+            row_slices=compiled.executor.plan.row_slices,
         )
         # overwriting a name must not strand the old plan in the cache
         self.registry.add(entry)
@@ -712,8 +713,8 @@ class SpmvEngine:
             # released) between the lookup and the swap
             with self._swap_lock:
                 if self.cache.peek(key) is not None:
-                    self.cache.get(key)  # mark MRU: it is about to serve
-                    self._swap_entry(entry, key, plan)
+                    # mark MRU: it is about to serve
+                    self._swap_entry(entry, key, plan, self.cache.get(key))
                     event["swapped"] = True
             if not event["swapped"]:
                 built = self._build(entry.matrix, plan, key, impl,
@@ -722,14 +723,14 @@ class SpmvEngine:
                 with self._swap_lock:
                     if self.cache.peek(key) is not None:
                         built.release()  # lost a race; the cached one wins
-                        self.cache.get(key)
-                        self._swap_entry(entry, key, plan)
+                        self._swap_entry(entry, key, plan,
+                                         self.cache.get(key))
                     else:
                         # evict-old before put: net-zero occupancy when the
                         # old key was unshared (the common case); a shared
                         # old key falls back to the normal LRU capacity
                         # contract on insert
-                        self._swap_entry(entry, key, plan)
+                        self._swap_entry(entry, key, plan, built)
                         self.cache.put(built)
                 event["swapped"] = True
         entry.tuned = True
@@ -745,12 +746,13 @@ class SpmvEngine:
         return event
 
     def _swap_entry(self, entry: RegisteredMatrix, key: PlanKey,
-                    plan: Plan) -> None:
+                    plan: Plan, compiled: CompiledPlan) -> None:
         """Point ``entry`` at the new compiled plan and evict its old plan
         unless another registered name still shares it — net-zero cache
         occupancy, so a background swap never pushes a *different* matrix's
         only executable out of the LRU.  Caller holds ``_swap_lock``."""
         old_key, entry.cache_key, entry.plan = entry.cache_key, key, plan
+        entry.row_slices = compiled.executor.plan.row_slices
         if old_key != key and not any(
             e.cache_key == old_key for e in self.registry
         ):

@@ -47,6 +47,9 @@ class RegisteredMatrix:
     register_s: dict = field(default_factory=dict)  # seconds of each
     # register phase that ran (stats, fingerprint, plan, partition, place,
     # compile), from the spmv.register.<phase> spans' boundaries
+    row_slices: Optional[dict] = None  # the XLA scalar tile kernel's
+    # layout: slice width, padded share and partials merged per SpMV
+    # (ExecutionPlan.row_slices; None for other kernels)
 
     def summary(self) -> dict:
         """JSON-safe identity + serving state — what crosses a process

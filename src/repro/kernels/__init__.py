@@ -9,6 +9,7 @@ Modules:
   coo_spmv.py    element-granular windowed kernel, one-hot MXU merge (lock-free)
   csr_spmv.py    row-granular planner over the windowed kernel
   ell_spmv.py    padded-row gather kernel (beyond-paper TPU-native format)
+  row_slices.py  row-slice layout + XLA tile kernel (COO/CSR on a mesh)
   ops.py         public dispatch (impl="xla" | "pallas"), spmv/spmm
   instrument.py  trace-time kernel-build counters (test observability)
   mode.py        interpret_mode: compiled on TPU, interpreted on CPU

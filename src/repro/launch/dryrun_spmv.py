@@ -71,8 +71,9 @@ def lower_1d(mat, mesh, ring=False):
     else:
         plan = plan_from_partitioned(mat, mesh)
     fn = plan.program(mat)  # shard_map call object; lowered against avals
+    arrs = {**D._arrays(mat), **(plan.placed_extra(mat) or {})}
     arrs_aval = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), D._arrays(mat)
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), arrs
     )
     x_aval = jax.ShapeDtypeStruct((mat.shape[1],), jnp.float32)
     with jax.set_mesh(mesh):

@@ -34,7 +34,8 @@ def main():
                          ("bcoo", "nnz")]:
         kw = dict(block=(4, 8)) if fmt == "bcoo" else {}
         part = partition_1d(a, 8, fmt=fmt, balance=balance, **kw)
-        arrs = D.place_1d(part, mesh1, "data")
+        extra = D.slice_arrays(part).arrays if fmt == "coo" else None
+        arrs = D.place_1d(part, mesh1, "data", extra=extra)
         xs = jax.device_put(jnp.asarray(x), jax.NamedSharding(mesh1, P("data")))
         out = D.spmv_1d(part, mesh1, "data")(arrs, xs)
         got = D.assemble_rows(out)
@@ -47,7 +48,8 @@ def main():
                           ("equally-wide", "global"),
                           ("variable-sized", "global")]:
         part = partition_2d(a, (4, 2), fmt="coo", scheme=scheme)
-        arrs = D.place_2d(part, mesh2, ("data", "model"))
+        arrs = D.place_2d(part, mesh2, ("data", "model"),
+                          extra=D.slice_arrays(part).arrays)
         xs = jax.device_put(jnp.asarray(x), jax.NamedSharding(mesh2, P("model")))
         out = D.spmv_2d(part, mesh2, ("data", "model"), merge=merge)(arrs, xs)
         got = D.assemble_rows(out)
@@ -66,7 +68,7 @@ def main():
     # SpMM through the distributed path (batch of vectors)
     X = rng.standard_normal((256, 4)).astype(np.float32)
     part = partition_1d(a, 8, fmt="coo", balance="nnz")
-    arrs = D.place_1d(part, mesh1, "data")
+    arrs = D.place_1d(part, mesh1, "data", extra=D.slice_arrays(part).arrays)
     xs = jax.device_put(jnp.asarray(X), jax.NamedSharding(mesh1, P("data", None)))
     out = D.spmv_1d(part, mesh1, "data")(arrs, xs)
     ok = np.allclose(D.assemble_rows(out), a @ X, rtol=1e-3, atol=1e-4)

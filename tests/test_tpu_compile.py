@@ -10,6 +10,7 @@ process at a time may load the TPU library, and under several pytest
 workers only the worker that runs this file may do so.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -127,3 +128,52 @@ def test_auto_and_tune_offer_only_block_formats(topo, block_sm):
     cands = CandidateGenerator(impls=("pallas",)).plans(
         block_sm, devices=topo.devices)
     assert cands and {p.fmt for p in cands} <= {"bcoo", "bcsr"}
+
+
+@pytest.mark.parametrize("scheme,width,slices,nnz", [
+    ("1d.nnz", 8, 123356, 881178),  # graph500-kron-s15: merged slices
+    ("2d.equally-sized", 27, COLS, 830584),  # hpcg-27pt-32: one a row
+])
+def test_xla_scalar_tile_kernel_compiles(topo, scheme, width, slices, nnz):
+    """The row-slice tile program at the benchmark cells' sizes compiles for
+    one v5e chip with every kernel op under ``spmv.coo.xla``; one slice a
+    row leaves no scatter in the program."""
+    from jax.sharding import Mesh
+
+    from repro.core.partition import PartitionedMatrix
+
+    one_slice = slices == COLS
+    one = jnp.zeros((1,), jnp.int32)
+    ext = jnp.full((1,), COLS, jnp.int32)
+    part = PartitionedMatrix(
+        rowind=one[None], colind=one[None], values=one[None].astype(float),
+        nnz=one, row_start=one, col_start=one, row_extent=ext,
+        col_extent=ext, shape=(COLS, COLS), grid=(1, 1), fmt="coo",
+        scheme=scheme, block=(1, 1), h_pad=COLS, w_pad=COLS)
+    shapes = {"rowind": ((nnz,), jnp.int32), "colind": ((nnz,), jnp.int32),
+              "values": ((nnz,), jnp.float32), "nnz": ((), jnp.int32),
+              "row_start": ((), jnp.int32), "col_start": ((), jnp.int32),
+              "slice_colind": ((width, slices), jnp.int32),
+              "slice_values": ((width, slices), jnp.float32)}
+    if not one_slice:
+        shapes["slice_row"] = ((slices,), jnp.int32)
+    if scheme.startswith("1d"):
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        program, lead, x_spec = D.spmv_1d(part, mesh), (1,), P("data")
+    else:
+        mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1),
+                    ("data", "model"))
+        program, lead, x_spec = D.spmv_2d(part, mesh), (1, 1), P("model")
+    tiles = NamedSharding(mesh, P(*mesh.axis_names))
+    avals = {k: _spec(lead + s, dt, tiles) for k, (s, dt) in shapes.items()}
+    x = _spec((COLS,), jnp.float32, NamedSharding(mesh, x_spec))
+    text = jax.jit(program.jitted).lower(avals, x).compile().as_text()
+    assert ("scatter" in text) != one_slice
+    named = re.findall(r"= \S+ (gather|scatter|reduce|fusion)\(.*?"
+                       r'op_name="([^"]*)"', text)
+    assert any(op == "gather" for op, _ in named)
+    # a relayout of a placed input to the shard's view keeps the input's
+    # name (arrs[...]), as the partition arrays' did before row slices
+    outside = [n for n in named
+               if "spmv.coo.xla" not in n[1] and not n[1].startswith("arrs[")]
+    assert not outside, outside
