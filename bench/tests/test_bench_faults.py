@@ -3,10 +3,14 @@
 Each test drives a whole small run on the CPU (everything but the look for a
 chip) with one fault planted in the program: a solver step that returns its
 state unchanged, a tolerance solve cut short, and an answer altered where
-the kernel produces it.  The
-cells batch no requests, so no batch can lose half its members, and they
-run on one chip, so there is no exchange between chips to leave out.
+the kernel produces it.  Each fault is planted in every place that the
+timed path can reach it: every built-in combine, and every tile kernel of
+a scalar-format plan.  The cells batch no requests, so no batch can lose
+half its members, and they run on one chip, so there is no exchange between
+chips to leave out.
 """
+import importlib
+
 import pytest
 
 from bench.harness import manifest as M
@@ -19,12 +23,17 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 SOLVE = [c for c in CELLS
          if issubclass(M.driver(M.resolve(c).traffic["driver"]), SolveLoop)]
 
+# Every tile kernel that a COO or CSR plan can run on a mesh of one chip or
+# more, as "module.function"; a new tile kernel is added here.
+TILE_KERNELS = ("repro.kernels.row_slices.row_slice_spmv",
+                "repro.kernels.ref.coo_spmv_ref")
+
 
 @pytest.mark.parametrize("cell", SOLVE)
 def test_step_that_returns_its_state_unchanged(cell, monkeypatch):
     from repro.api import iterate
 
-    for cls in (iterate.CGCombine, iterate.CallableCombine):
+    for cls in (*iterate.COMBINES.values(), iterate.CallableCombine):
         monkeypatch.setattr(cls, "step",
                             lambda self, carry, y, params: carry)
     assert not run_small(cell)["correct"]
@@ -50,12 +59,16 @@ def test_tolerance_solve_cut_short(cell, monkeypatch):
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_answer_altered_where_it_is_produced(cell, monkeypatch):
-    from repro.kernels import ref
+    def altered(exact):
+        return lambda *args, **kwargs: exact(*args, **kwargs) * 1.001
 
-    exact = ref.coo_spmv_ref
-
-    def altered(*args, **kwargs):
-        return exact(*args, **kwargs) * 1.001
-
-    monkeypatch.setattr(ref, "coo_spmv_ref", altered)
+    for target in TILE_KERNELS:
+        module_name, _, name = target.rpartition(".")
+        try:
+            module = importlib.import_module(module_name)
+        except ModuleNotFoundError:
+            continue  # a tree from before this kernel
+        exact = getattr(module, name, None)
+        if exact is not None:
+            monkeypatch.setattr(module, name, altered(exact))
     assert not run_small(cell)["correct"]

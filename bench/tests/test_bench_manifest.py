@@ -61,6 +61,14 @@ def test_every_config_is_used_and_names_its_file():
     assert len(files) == len(BENCH["configs"])
 
 
+@pytest.mark.parametrize("path", sorted(
+    (M.BENCH / "configs").glob("*.json")), ids=lambda p: p.stem)
+def test_every_config_states_its_small_size(path):
+    # the tests run every cell on the CPU at its configuration's ``small``
+    cfg = M.load_json(path)
+    assert cfg["small"] and set(cfg["small"]) <= set(cfg["params"])
+
+
 def test_names_units_and_text_fields():
     names = ([c["name"] for c in BENCH["configs"]] + CELLS
              + [m["name"] for m in METRICS])
