@@ -27,6 +27,7 @@ from jax.sharding import NamedSharding
 
 from repro.core import distributed as D
 from repro.core.partition import PartitionedMatrix
+from repro.core.semiring import lookup
 from repro.kernels import ops
 from repro.obs import NULL_TRACE
 
@@ -44,6 +45,11 @@ class Executor:
     """Common surface: ``exe(x) -> y`` and ``exe.batch(X) -> Y`` (host rows)."""
 
     plan = None  # the ExecutionPlan this executor was compiled from
+
+    @property
+    def semiring(self) -> str:
+        """The semiring the plan runs: ``plus_times`` or ``min_plus``."""
+        return "plus_times" if self.plan is None else self.plan.semiring
 
     def __call__(self, x) -> np.ndarray:
         """y = A @ x for one vector x of shape (cols,); returns host rows."""
@@ -299,7 +305,8 @@ class MeshExecutor(Executor):
         """
         meta = self.assemble_meta
         if self.plan is not None and self.plan.partitioning == "1d":
-            out = D.SpmvOutput(raw, merge="none", **meta)
+            out = D.SpmvOutput(raw, merge="none", semiring=self.semiring,
+                               **meta)
         elif self.merge == "global":
             out = D.SpmvOutput(raw, merge="global",
                                replicated_global=raw[0, 0][: meta["rows"]],
@@ -375,6 +382,7 @@ class MeshExecutor(Executor):
                       for r, e in zip(row_start, meta["row_extent"])]
         is_1d = self.plan is not None and self.plan.partitioning == "1d"
         merge = self.merge
+        sr = lookup(self.semiring)
 
         def assemble_dev(raw):
             if not is_1d and merge == "global":
@@ -388,12 +396,13 @@ class MeshExecutor(Executor):
                              else raw[r].reshape((-1,) + raw.shape[3:]))
                     y = y.at[r0:r0 + ext].set(block[:ext])
                 return y
-            # 1D per-part slices: duplicates on shared boundary rows are
-            # zero (the ppermute moved them), so add order matches the host
-            y = jnp.zeros((rows,) + raw.shape[2:], raw.dtype)
+            # 1D per-part slices: duplicates on shared boundary rows hold
+            # the semiring's zero (the ppermute moved them), so the sum's
+            # order matches the host's
+            y = sr.fill((rows,) + raw.shape[2:], raw.dtype)
             for p in range(raw.shape[0]):
                 r0, ext = row_start[p], row_extent[p]
-                y = y.at[r0:r0 + ext].add(raw[p][:ext])
+                y = sr.scatter(y.at[r0:r0 + ext], raw[p][:ext])
             return y
 
         def apply(v):

@@ -22,8 +22,9 @@ Two loop modes:
 
 Built-in combines (:func:`make_combine`): ``plain`` (x' = y), ``power``
 (normalize), ``richardson`` / ``jacobi`` (damped residual correction toward
-``A x = b``), ``cg`` (conjugate gradients on SPD systems), plus any
-user-supplied ``f(x, y) -> x_next`` callable as the escape hatch.
+``A x = b``), ``cg`` (conjugate gradients on SPD systems), ``sssp``
+(Bellman-Ford relaxation over a (min, +) matrix, fed only the frontier),
+plus any user-supplied ``f(x, y) -> x_next`` callable as the escape hatch.
 
 The compiled loop is cached on the executor per (combine, mode, static
 knobs); ``b`` / ``diag`` / ``omega`` / ``tol`` enter as runtime arguments,
@@ -64,6 +65,9 @@ class IterateResult:
     kernel_s: float  # the compiled solver loop
     retrieve_s: float  # fetch x + scalars back to host
     compiled: bool = False  # this call built+compiled the loop (cold start)
+    # the combine's counter (Combine.counter), summed over the executed
+    # steps; "sssp": the frontier's size.  None for combines that keep none
+    frontier_total: Optional[int] = None
 
     @property
     def seconds(self) -> float:
@@ -84,12 +88,14 @@ class Combine:
     a dict pytree carrying at least ``x`` (the current iterate) and ``res``
     (the residual the tol loop tests).  ``linear=True`` marks combines whose
     step is an affine map of the state — exactly the ones for which k
-    scanned steps must be bit-identical to k host-side calls.
+    scanned steps must be bit-identical to k host-side calls.  ``semiring``
+    is the one the matrix must run (None: any).
     """
 
     name = "combine"
     linear = False
     needs_b = False
+    semiring = "plus_times"
 
     def init(self, x0, params, apply) -> dict:
         return {"x": x0, "res": jnp.asarray(jnp.inf, x0.dtype)}
@@ -105,6 +111,10 @@ class Combine:
 
     def residual(self, carry):
         return carry["res"]
+
+    def counter(self, carry):
+        """An int32 the carry keeps over the steps, or None."""
+        return None
 
 
 class PlainCombine(Combine):
@@ -185,10 +195,50 @@ class CGCombine(Combine):
                 "res": jnp.sqrt(rs_new)}
 
 
+class SSSPCombine(Combine):
+    """Single-source shortest paths on a (min, +) matrix: Bellman-Ford
+    relaxation fed only the frontier.
+
+    x holds distances (+inf: not reached).  The SpMV takes the frontier's
+    distances, +inf elsewhere, so y_i = min_j (A_ij + x_j) over the vertices
+    j whose distance fell last step; then x' = min(x, y), and the frontier
+    is where x' < x.  ``res`` is the frontier's size, so a tol loop with
+    tol < 1 stops at the fixed point, and a step past it changes nothing.
+    The counter sums the size of the frontier each step is fed.
+    """
+
+    name = "sssp"
+    semiring = "min_plus"
+
+    def init(self, x0, params, apply):
+        frontier = x0 < jnp.inf
+        return {"x": x0, "frontier": frontier,
+                "res": jnp.sum(frontier).astype(x0.dtype),
+                "frontier_total": jnp.asarray(0, jnp.int32)}
+
+    def vector(self, carry):
+        return jnp.where(carry["frontier"], carry["x"],
+                         jnp.asarray(jnp.inf, carry["x"].dtype))
+
+    def step(self, carry, y, params):
+        x = carry["x"]
+        x_new = jnp.minimum(x, y)
+        frontier = x_new < x
+        total = carry["frontier_total"] + jnp.sum(carry["frontier"],
+                                                  dtype=jnp.int32)
+        return {"x": x_new, "frontier": frontier,
+                "res": jnp.sum(frontier).astype(x.dtype),
+                "frontier_total": total}
+
+    def counter(self, carry):
+        return carry["frontier_total"]
+
+
 class CallableCombine(Combine):
     """Escape hatch: any ``f(x, y) -> x_next`` (residual = ||x' - x||)."""
 
     name = "callable"
+    semiring = None
 
     def __init__(self, fn: Callable):
         self.fn = fn
@@ -205,6 +255,7 @@ COMBINES = {
     "richardson": RichardsonCombine,
     "jacobi": JacobiCombine,
     "cg": CGCombine,
+    "sssp": SSSPCombine,
 }
 
 
@@ -282,7 +333,7 @@ def run_iterate(
         checked every ``check_every`` steps — no per-step host sync), or
         until ``max_steps``.
       combine: builtin name (``plain`` / ``power`` / ``richardson`` /
-        ``jacobi`` / ``cg``) or a callable ``f(x, y) -> x_next``.
+        ``jacobi`` / ``cg`` / ``sssp``) or a callable ``f(x, y) -> x_next``.
       b: right-hand side for richardson/jacobi/cg.
       diag: matrix diagonal for jacobi.
       omega: richardson damping factor.
@@ -300,7 +351,8 @@ def run_iterate(
 
     Raises:
       ValueError: for both/neither of steps and tol, a non-square executor
-        (callers check), bad combine/params, or a batched x0.
+        (callers check), bad combine/params, a combine of another semiring
+        than the executor's, or a batched x0.
     """
     if (steps is None) == (tol is None):
         raise ValueError("iterate needs exactly one of steps= or tol=")
@@ -316,6 +368,10 @@ def run_iterate(
             raise ValueError(f"iterate takes a single (n,) start vector; "
                              f"got shape {x0.shape}")
         comb = make_combine(combine)
+        if comb.semiring not in (None, executor.semiring):
+            raise ValueError(
+                f"combine {comb.name!r} runs on a {comb.semiring} matrix; "
+                f"this one is registered {executor.semiring}")
         params = _build_params(comb, n, dtype, b, diag, omega)
         params["tol"] = jnp.asarray(0.0 if tol is None else float(tol), dtype)
         x0_dev = jnp.asarray(x0)
@@ -340,6 +396,8 @@ def run_iterate(
     with phase("spmv.solve.fetch", trace, span="retrieve") as fetch:
         steps_run = int(k)
         residual = float(carry["res"])
+        counter = comb.counter(carry)
+        frontier_total = None if counter is None else int(counter)
         x_host = np.asarray(x)
 
     return IterateResult(
@@ -351,6 +409,7 @@ def run_iterate(
         kernel_s=run.seconds,
         retrieve_s=fetch.seconds,
         compiled=cold,
+        frontier_total=frontier_total,
     )
 
 

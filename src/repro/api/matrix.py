@@ -25,6 +25,7 @@ import numpy as np
 from repro import compat
 from repro.core import formats as F
 from repro.core.adaptive import HardwareModel, Plan, estimate_time
+from repro.core.semiring import lookup
 from repro.core.stats import MatrixStats, compute_stats
 from repro.kernels.mode import interpret_mode
 
@@ -49,6 +50,16 @@ def fingerprint_matrix(a: np.ndarray) -> str:
     return h.hexdigest()[:16]
 
 
+def _fingerprint_entries(semiring: str, shape, rowind, colind,
+                         values) -> str:
+    """Content hash of a semiring's stored entries (a stored 0 included)."""
+    h = hashlib.sha256()
+    h.update(repr((semiring, tuple(shape), values.dtype.str)).encode())
+    for arr in (rowind.astype(np.int64), colind.astype(np.int64), values):
+        h.update(np.ascontiguousarray(arr).tobytes())
+    return h.hexdigest()[:16]
+
+
 class SparseMatrix:
     """A sparse matrix plus its stats, behind every SpMV entry point."""
 
@@ -67,7 +78,8 @@ class SparseMatrix:
         self.dtype = np.dtype(dtype)
         self._stats_block = stats_block
         self._stats: Optional[MatrixStats] = None
-        self._fingerprint: Optional[str] = None
+        self._fingerprints: dict = {}  # semiring -> fingerprint
+        self._entries = None  # the triplets as entries (entries())
 
     # ------------------------------------------------------------ constructors
 
@@ -205,11 +217,58 @@ class SparseMatrix:
     def nnz(self) -> int:
         return self.stats.nnz
 
-    def fingerprint(self) -> str:
-        """Content hash — the identity under which compiled plans are cached."""
-        if self._fingerprint is None:
-            self._fingerprint = fingerprint_matrix(self.dense())
-        return self._fingerprint
+    def entries(self):
+        """The triplets as stored entries: row-sorted ``(rowind, colind,
+        values)``, each coordinate once, in the matrix dtype, never densified.
+
+        Every triplet is an entry, a stored 0 included, and duplicate
+        coordinates keep the least value: a (min, +) matrix's edges, where
+        a 0 is an edge of weight 0 and of parallel edges only the lightest
+        counts.
+
+        Raises:
+          ValueError: on a matrix not built from triplets (``from_parts`` /
+            ``from_scipy``): a dense array cannot say which zeros are edges.
+        """
+        if self._triplets is None:
+            raise ValueError(
+                "a (min, +) matrix needs its triplets (SparseMatrix.from_parts "
+                "or from_scipy): a dense array cannot say which zeros are "
+                "edges")
+        if self._entries is None:
+            ri, ci, vals = self._triplets
+            order = np.lexsort((vals.astype(np.float64), ci, ri))
+            ri, ci, vals = ri[order], ci[order], vals[order]
+            first = np.ones(len(ri), bool)
+            first[1:] = (ri[1:] != ri[:-1]) | (ci[1:] != ci[:-1])
+            # sorted by value within a coordinate: its first is its least
+            self._entries = (ri[first], ci[first],
+                             vals[first].astype(self.dtype, copy=False))
+        return self._entries
+
+    def fingerprint(self, semiring: str = "plus_times") -> str:
+        """Content hash — the identity under which compiled plans are cached.
+
+        Under ``plus_times`` it hashes the dense array's nonzeros
+        (:func:`fingerprint_matrix`); under another semiring, the semiring's
+        name and its stored entries (:meth:`entries`), so the same triplets
+        under two semirings, or with and without a stored 0, never share a
+        compiled plan.
+
+        Raises:
+          ValueError: for an unknown semiring, or another than (+, x) on a
+            matrix not built from triplets (:meth:`entries`).
+        """
+        lookup(semiring)
+        fp = self._fingerprints.get(semiring)
+        if fp is None:
+            if semiring == "plus_times":
+                fp = fingerprint_matrix(self.dense())
+            else:
+                fp = _fingerprint_entries(semiring, self.shape,
+                                          *self.entries())
+            self._fingerprints[semiring] = fp
+        return fp
 
     def container(self, fmt: str, block: Tuple[int, int] = (8, 16),
                   dtype=None):
@@ -273,6 +332,7 @@ class SparseMatrix:
         batch: Optional[int] = None,
         topology=None,
         assignment=None,
+        semiring: str = "plus_times",
     ) -> ExecutionPlan:
         """Resolve scheme + placement into an inspectable ExecutionPlan.
 
@@ -316,6 +376,11 @@ class SparseMatrix:
             :class:`repro.topo.AxisAssignment` or its dict form) instead of
             the model's pick — how ``repro.tune`` measures one candidate
             per assignment.  Requires ``topology``.
+          semiring: ``"plus_times"`` (y = A x) or ``"min_plus"``
+            (y_i = min_j A_ij + x_j over the stored entries, a stored 0
+            included).  ``min_plus`` needs a matrix built from triplets and
+            runs on a mesh with a 1D scheme, a scalar format and
+            impl="xla"; ``"auto"`` picks such a plan.
 
         Returns:
           An inspectable :class:`~repro.api.plan.ExecutionPlan`; call
@@ -325,8 +390,10 @@ class SparseMatrix:
 
         Raises:
           ValueError: unknown impl/scheme, both mesh= and devices= given,
-            a user mesh whose shape the fitted plan cannot lay out on, or
-            impl="pallas" with a scalar format (coo/csr) on TPU.
+            a user mesh whose shape the fitted plan cannot lay out on,
+            impl="pallas" with a scalar format (coo/csr) on TPU, or a
+            ``min_plus`` plan that is not a 1D scalar XLA plan on a mesh
+            (:func:`~repro.api.plan.check_semiring`).
         """
         if impl not in ("xla", "pallas"):
             raise ValueError(f"unknown impl {impl!r}: 'xla' or 'pallas'")
@@ -342,6 +409,9 @@ class SparseMatrix:
                     "topology= is abstract (no devices); pass devices= too"
                 )
         if scheme == "tune":
+            if semiring != "plus_times":
+                raise ValueError(f"semiring {semiring!r}: scheme='tune' "
+                                 "measures (+, x) plans only")
             # measure-and-refine: delegate to repro.tune (lazy import — the
             # tuner itself plans through this very method)
             overrides = dict(partitioning=partitioning, fmt=fmt, merge=merge,
@@ -385,6 +455,7 @@ class SparseMatrix:
             partitioning=partitioning, fmt=fmt, merge=merge, grid=grid,
             block=block, fit=fit, topology=topology,
             dtype_bytes=self.dtype.itemsize, compiled_pallas=compiled_pallas,
+            semiring=semiring,
         )
         if mesh is not None:
             # fail fast: the fitted plan must lay out on the given mesh, or
@@ -445,7 +516,7 @@ class SparseMatrix:
             matrix=self, scheme=plan, impl=impl,
             mesh=mesh if distributed else None, dtype=self.dtype,
             block=tuple(block), hw=hw, estimate=est,
-            topo_assignment=topo_assignment,
+            topo_assignment=topo_assignment, semiring=semiring,
         )
 
     def compile(self, **plan_kwargs):

@@ -19,6 +19,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 from typing import Optional, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
@@ -29,8 +30,10 @@ from repro.core.partition import (
     SCHEMES_2D,
     PartitionedMatrix,
     partition_1d,
+    partition_1d_coo,
     partition_2d,
 )
+from repro.core.semiring import lookup
 from repro.core.stats import MatrixStats
 from repro.kernels.mode import interpret_mode
 
@@ -38,6 +41,7 @@ from .executor import AXES_2D, AXIS_1D, Executor, MeshExecutor, SingleDeviceExec
 
 __all__ = [
     "ExecutionPlan",
+    "check_semiring",
     "fit_plan",
     "resolve_scheme",
     "plan_from_partitioned",
@@ -47,6 +51,7 @@ __all__ = [
 
 FORMATS = ("coo", "csr", "bcoo", "bcsr")
 _BLOCK_FORMAT_OF = {"coo": "bcoo", "csr": "bcsr"}
+_SCALAR_FORMATS = tuple(_BLOCK_FORMAT_OF)
 
 # Plan-IR format version.  Bump when the IR schema changes shape in a way an
 # older reader cannot interpret; ``plan_from_ir`` rejects unknown versions
@@ -189,6 +194,41 @@ def check_compiled_pallas(fmt: str) -> None:
         )
 
 
+def check_semiring(semiring: str, plan: Plan, *, impl: str,
+                   distributed: bool, ring: bool = False, dtype=None) -> None:
+    """Refuse a plan whose kernel cannot run ``semiring``.
+
+    (+, x) runs everywhere.  Another semiring runs only on the XLA
+    row-slice kernel (:func:`repro.kernels.row_slices.row_slice_spmv`) of a
+    scalar format (coo/csr) under a 1D scheme on a mesh, whose boundary
+    merge knows it (:func:`repro.core.distributed.spmv_1d`), with float
+    values (its zero is +inf).
+
+    Raises:
+      ValueError: for an unknown semiring, or naming the semiring and each
+        part of the plan that refuses it.
+    """
+    if lookup(semiring).name == "plus_times":
+        return
+    refused = []
+    if not distributed:
+        refused.append("a single-device plan (pass devices= or mesh=)")
+    if plan.partitioning != "1d":
+        refused.append(f"a {plan.partitioning} partition")
+    if ring:
+        refused.append("the 1D ring schedule")
+    if plan.fmt not in _SCALAR_FORMATS:
+        refused.append(f"fmt={plan.fmt!r}")
+    if impl != "xla":
+        refused.append(f"impl={impl!r}")
+    if dtype is not None and not jnp.issubdtype(dtype, jnp.floating):
+        refused.append(f"dtype {np.dtype(dtype).name}")
+    if refused:
+        raise ValueError(
+            f"semiring {semiring!r} runs only on 1D coo/csr plans with "
+            f"impl='xla' on a mesh; refused: {', '.join(refused)}")
+
+
 def resolve_scheme(
     stats: MatrixStats,
     shape: tuple,
@@ -205,6 +245,7 @@ def resolve_scheme(
     topology=None,
     dtype_bytes: int = 4,
     compiled_pallas: bool = False,
+    semiring: str = "plus_times",
 ) -> Plan:
     """Turn "auto" / a scheme string / an adaptive.Plan into a fitted Plan.
 
@@ -212,13 +253,20 @@ def resolve_scheme(
     fitting collective-cost-aware — see :func:`fit_plan`.
     ``compiled_pallas`` says the plan runs Pallas kernels compiled for TPU:
     ``"auto"`` then picks the block counterpart of a scalar format, and a
-    fitted scalar format raises (:func:`check_compiled_pallas`).
+    fitted scalar format raises (:func:`check_compiled_pallas`).  Under a
+    ``semiring`` other than (+, x), ``"auto"`` picks 1D element-balanced
+    COO unless the rules already chose a 1D scalar plan
+    (:func:`check_semiring`).
     """
     hw = hw if hw is not None else HardwareModel(chips=max(1, n_devices))
     if isinstance(scheme, Plan):
         plan = scheme
     elif scheme == "auto":
         plan = select_scheme(stats, hw)
+        if semiring != "plus_times" and (plan.partitioning != "1d" or
+                                         plan.fmt not in _SCALAR_FORMATS):
+            plan = Plan("1d", "nnz", "coo", "ppermute", (n_devices, 1),
+                        f"{semiring}: 1D scalar plans only")
         if compiled_pallas:
             plan = replace(plan, fmt=_BLOCK_FORMAT_OF.get(plan.fmt, plan.fmt))
         if partitioning is not None and plan.partitioning != partitioning:
@@ -278,6 +326,12 @@ class ExecutionPlan:
     # the XLA scalar tile kernel's row-slice layout, set by compile():
     # {"width", "padded_share", "merged"} (repro.kernels.row_slices)
     row_slices: Optional[dict] = None
+    semiring: str = "plus_times"  # "plus_times" | "min_plus"
+
+    def __post_init__(self):
+        check_semiring(self.semiring, self.scheme, impl=self.impl,
+                       distributed=self.mesh is not None, ring=self.ring,
+                       dtype=self.dtype)
 
     # -- inspection --------------------------------------------------------
 
@@ -330,10 +384,12 @@ class ExecutionPlan:
         s = self.scheme
         where = (f"mesh{tuple(self.mesh.devices.shape)}" if self.is_distributed
                  else "single-device")
+        semiring = ("" if self.semiring == "plus_times"
+                    else f" semiring={self.semiring}")
         lines = [
             f"ExecutionPlan[{s.partitioning}.{s.scheme} fmt={s.fmt} "
             f"merge={s.merge} grid={tuple(s.grid)} impl={self.impl} "
-            f"dtype={np.dtype(self.dtype).name} {where}]",
+            f"dtype={np.dtype(self.dtype).name}{semiring} {where}]",
             f"  reason: {s.reason}",
         ]
         if self.estimate:
@@ -425,6 +481,7 @@ class ExecutionPlan:
             "estimate": {k: float(v) for k, v in self.estimate.items()},
             "measured": _jsonable(self.measured),
             "topo": _jsonable(self.topo_assignment),
+            "semiring": self.semiring,
         }
 
     # -- axes / specs ------------------------------------------------------
@@ -457,9 +514,16 @@ class ExecutionPlan:
 
     def partition(self) -> PartitionedMatrix:
         """The host partition of the matrix: ``part`` where the plan holds
-        one, else partitioned from the dense matrix."""
+        one, else partitioned from the dense matrix, or under a semiring
+        other than (+, x) from its entries (a stored 0 stays an entry)."""
         if self.part is not None:
             return self.part
+        if self.semiring != "plus_times":
+            ri, ci, vals = self.matrix.entries()
+            return partition_1d_coo(
+                ri, ci, vals.astype(self.dtype, copy=False),
+                self.matrix.shape, self.scheme.grid[0], fmt=self.fmt,
+                balance=self.scheme.scheme)
         a = self.matrix.dense()
         if a.dtype != self.dtype:
             a = a.astype(self.dtype)
@@ -480,7 +544,8 @@ class ExecutionPlan:
                     raise ValueError("the 1D ring schedule runs the XLA local "
                                      "kernel only (impl='xla')")
                 return D.spmv_1d_ring(part, self.ring_counts, self.mesh, axes[0])
-            return D.spmv_1d(part, self.mesh, axes[0], impl=self.impl)
+            return D.spmv_1d(part, self.mesh, axes[0], impl=self.impl,
+                             semiring=self.semiring)
         return D.spmv_2d(part, self.mesh, axes, merge=self.merge,
                          impl=self.impl)
 
@@ -494,7 +559,7 @@ class ExecutionPlan:
             return None
         if self.impl == "pallas":
             return D.pallas_chunk_arrays(part)
-        layout = D.slice_arrays(part)
+        layout = D.slice_arrays(part, self.semiring)
         self.row_slices = layout.summary()
         return layout.arrays
 
@@ -699,4 +764,5 @@ def plan_from_ir(ir: dict, matrix, *, devices=None, mesh=None,
                      else np.asarray(ring_counts, dtype=np.int64)),
         measured=dict(ir.get("measured") or {}),
         topo_assignment=topo_assignment,
+        semiring=ir.get("semiring", "plus_times"),
     )

@@ -37,6 +37,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.partition import PartitionedMatrix
+from repro.core.semiring import lookup
 from repro.kernels import ref as kref
 from repro.kernels import row_slices
 from repro.kernels.ops import kernel_scope
@@ -65,6 +66,7 @@ class SpmvOutput:
     rows: int
     merge: str = "none"  # none | psum | psum_scatter | global
     replicated_global: jax.Array | None = None  # set by 2D merge="global"
+    semiring: str = "plus_times"  # how 1D parts' rows combine on assembly
 
 
 def _pallas_span(h_pad: int) -> int:
@@ -116,9 +118,11 @@ def pallas_chunk_arrays(mat: PartitionedMatrix, chunk: int | None = None) -> dic
             if isinstance(v, np.ndarray)}
 
 
-def slice_arrays(mat: PartitionedMatrix) -> row_slices.SliceLayout:
+def slice_arrays(mat: PartitionedMatrix,
+                 semiring: str = "plus_times") -> row_slices.SliceLayout:
     """Host-side row-slice layout of a scalar-format partition, for the XLA
-    tile kernel (:mod:`repro.kernels.row_slices`).
+    tile kernel (:mod:`repro.kernels.row_slices`), padded with the
+    ``semiring``'s zero.
 
     Cuts each part's first ``nnz[p]`` entries into slices of one width,
     chosen from the row-length histogram of the whole partition, with one
@@ -132,10 +136,11 @@ def slice_arrays(mat: PartitionedMatrix) -> row_slices.SliceLayout:
                          "run bcoo_spmv_ref on the partition arrays")
     return row_slices.build_row_slices(
         np.asarray(mat.rowind), np.asarray(mat.colind),
-        np.asarray(mat.values), np.asarray(mat.nnz), mat.h_pad)
+        np.asarray(mat.values), np.asarray(mat.nnz), mat.h_pad, semiring)
 
 
-def _local_kernel(mat: PartitionedMatrix, impl: str, mesh):
+def _local_kernel(mat: PartitionedMatrix, impl: str, mesh,
+                  semiring: str = "plus_times"):
     """Build the per-shard kernel ``f(sl, x_local) -> y (h_pad[, B])``.
 
     impl="xla" runs the row-slice kernel for COO/CSR (its ``slice_*``
@@ -148,23 +153,25 @@ def _local_kernel(mat: PartitionedMatrix, impl: str, mesh):
     values dtype (accumulation happens wider inside, matching the oracle
     contract the merge collectives rely on).  The Pallas kernels compile
     for a TPU mesh and run in interpret mode on a CPU mesh.  The kernel's
-    ops sit under :func:`kernel_scope`.
+    ops sit under :func:`kernel_scope`.  ``semiring`` reaches the row-slice
+    kernel only; plans refuse (min, +) on every other kernel
+    (:func:`repro.api.plan.check_semiring`).
     """
-    local = _tile_kernel(mat, impl, mesh)
+    local = _tile_kernel(mat, impl, mesh, semiring)
 
     def named(sl, x_local):
-        with kernel_scope(mat.fmt, impl):
+        with kernel_scope(mat.fmt, impl, semiring):
             return local(sl, x_local)
 
     return named
 
 
-def _tile_kernel(mat: PartitionedMatrix, impl: str, mesh):
+def _tile_kernel(mat: PartitionedMatrix, impl: str, mesh, semiring: str):
     if impl == "xla":
         if mat.fmt in ("coo", "csr"):
             return lambda sl, x_local: row_slices.row_slice_spmv(
                 sl["slice_colind"], sl["slice_values"], x_local, mat.h_pad,
-                sl.get("slice_row"))
+                sl.get("slice_row"), semiring=semiring)
         return lambda sl, x_local: kref.bcoo_spmv_ref(
             sl["rowind"], sl["colind"], sl["values"], x_local, mat.h_pad,
             nblocks=sl["nnz"])
@@ -283,13 +290,16 @@ def spmv_1d(
     axis: str = "data",
     x_sharding_axis: str | None = None,
     impl: str = "xla",
+    semiring: str = "plus_times",
 ) -> callable:
     """Build jitted distributed 1D SpMV: (placed_arrays, x) -> SpmvOutput.
 
     x enters sharded over ``axis`` (its natural production placement) and is
     all-gathered inside — the paper's broadcast/load step, now on ICI.  Row-
     granular schemes need no merge; element-granular ('1d.nnz') corrects the
-    single split row per boundary with one collective_permute.
+    single split row per boundary with one collective_permute.  Under
+    ``semiring="min_plus"`` the split row's duplicate holds +inf in place of
+    0 and the neighbour's partial is merged by min in place of a sum.
 
     ``impl`` selects the per-shard tile kernel (XLA or the Pallas kernels);
     on scalar formats the placed arrays must include the kernel's layout —
@@ -303,17 +313,22 @@ def spmv_1d(
     rp = jnp.asarray(recv_pos.astype(np.int32))
     needs_merge = mat.scheme == "1d.nnz"
     perm = [(i, i - 1) for i in range(1, Pn)]
-    local = _local_kernel(mat, impl, mesh)
+    local = _local_kernel(mat, impl, mesh, semiring)
+    sr = lookup(semiring)
+
+    def zero(v):  # a row with nothing to merge
+        return sr.fill(v.shape, v.dtype)
 
     def _step(arrs, hs_l, ns_l, rp_l, x_shard):
         sl = _slice0(arrs)
         x_full = jax.lax.all_gather(x_shard, axis, tiled=True)
         y = local(sl, x_full)  # (h_pad[, B])
         if needs_merge and Pn > 1:
-            send = jnp.where(hs_l[0], y[0], jnp.zeros_like(y[0]))
+            send = jnp.where(hs_l[0], y[0], zero(y[0]))
             recv = jax.lax.ppermute(send, axis, perm)
-            y = y.at[0].set(jnp.where(hs_l[0], jnp.zeros_like(y[0]), y[0]))
-            y = y.at[rp_l[0]].add(jnp.where(ns_l[0], recv, jnp.zeros_like(recv)))
+            y = y.at[0].set(jnp.where(hs_l[0], zero(y[0]), y[0]))
+            y = sr.scatter(y.at[rp_l[0]],
+                           jnp.where(ns_l[0], recv, zero(recv)))
         return y[None]
 
     shmap = jax.shard_map(
@@ -333,6 +348,7 @@ def spmv_1d(
         row_start=np.asarray(mat.row_start),
         row_extent=np.asarray(mat.row_extent),
         rows=mat.shape[0],
+        semiring=semiring,
     )
 
     def call(arrs, x_shard) -> SpmvOutput:
@@ -626,7 +642,8 @@ def assemble_rows(out: SpmvOutput) -> np.ndarray:
 
     1D (merge="none"): sum per-part slices into their row ranges — the
     boundary ppermute already moved shared-row values to their owner, so
-    overlapping duplicates are zero.
+    overlapping duplicates are zero (under ``min_plus``: the least, from
+    +inf, duplicates +inf).
     2D psum: every column of the grid holds the merged row-block — take col 0.
     2D psum_scatter: device (r, c) holds segment c of row-block r.
     2D global: already replicated.
@@ -654,9 +671,10 @@ def assemble_rows(out: SpmvOutput) -> np.ndarray:
             y[r0 : r0 + ext] = block[:ext]
         return y
     # 1D parts
-    y = np.zeros((out.rows,) + yp.shape[2:], yp.dtype)
+    sr = lookup(out.semiring)
+    y = np.full((out.rows,) + yp.shape[2:], sr.zero, yp.dtype)
     for p in range(yp.shape[0]):
         r0 = int(out.row_start[p])
         ext = min(int(out.row_extent[p]), out.rows - r0)
-        y[r0 : r0 + ext] += yp[p][:ext]
+        sr.host_plus(y[r0 : r0 + ext], yp[p][:ext], out=y[r0 : r0 + ext])
     return y

@@ -39,6 +39,7 @@ import numpy as np
 __all__ = [
     "PartitionedMatrix",
     "partition_1d",
+    "partition_1d_coo",
     "partition_2d",
     "BALANCE_1D",
     "SCHEMES_2D",
@@ -182,15 +183,47 @@ def partition_1d(
                         SpMV merges at most one boundary row per neighbor pair
                         (paper §3.3.1).
     """
-    rows, cols = a.shape
     if fmt in ("csr", "coo"):
-        ri, ci, vals = _as_sorted_coo(a)
-        unit_rows, r_blk = rows, 1
-    elif fmt in ("bcsr", "bcoo"):
-        ri, ci, vals = _as_sorted_block_coo(a, block)
+        return partition_1d_coo(*_as_sorted_coo(a), a.shape, parts, fmt=fmt,
+                                balance=balance)
+    if fmt not in ("bcsr", "bcoo"):
+        raise ValueError(f"unknown fmt {fmt!r}")
+    return _partition_1d_sorted(*_as_sorted_block_coo(a, block), a.shape,
+                                parts, fmt, balance, block)
+
+
+def partition_1d_coo(
+    rowind: np.ndarray,
+    colind: np.ndarray,
+    values: np.ndarray,
+    shape: Tuple[int, int],
+    parts: int,
+    fmt: str = "coo",
+    balance: str = "nnz",
+) -> PartitionedMatrix:
+    """:func:`partition_1d` of a scalar format from its entries, never dense.
+
+    Args:
+      rowind, colind, values: the entries, sorted by row then column, each
+        coordinate once; every one is stored, a value of 0 included.
+      shape: global (rows, cols).
+      parts, fmt, balance: as :func:`partition_1d` (``fmt`` "coo"/"csr").
+    """
+    if fmt not in ("csr", "coo"):
+        raise ValueError(f"partition_1d_coo takes a scalar format; got {fmt!r}")
+    return _partition_1d_sorted(np.asarray(rowind, np.int64),
+                                np.asarray(colind, np.int64),
+                                np.asarray(values), shape, parts, fmt,
+                                balance, (1, 1))
+
+
+def _partition_1d_sorted(ri, ci, vals, shape, parts, fmt, balance, block):
+    """The 1D split of row-sorted entries (block units for block formats)."""
+    rows, cols = shape
+    if fmt in ("bcsr", "bcoo"):
         unit_rows, r_blk = rows // block[0], block[0]
     else:
-        raise ValueError(f"unknown fmt {fmt!r}")
+        unit_rows, r_blk = rows, 1
     nnz_total = len(ri)
 
     if balance == "rows":

@@ -179,7 +179,8 @@ class SpmvEngine:
             if entry.cache_key == compiled.key:
                 entry.spill = compiled.part
 
-    def _plan(self, sm: SparseMatrix, plan: Plan, impl: str, assignment):
+    def _plan(self, sm: SparseMatrix, plan: Plan, impl: str, assignment,
+              semiring: str):
         """The ExecutionPlan of ``plan`` on this engine's pool."""
         if self.topology is not None:
             # let plan() place the mesh by collective cost (device order
@@ -187,18 +188,19 @@ class SpmvEngine:
             return sm.plan(
                 scheme=plan, devices=self.devices, topology=self.topology,
                 impl=impl, block=self.block, hw=self.hw,
-                assignment=assignment,
+                assignment=assignment, semiring=semiring,
             )
         if plan.partitioning == "1d":
             mesh = self._mesh((plan.grid[0],), (_AXIS_1D,))
         else:
             mesh = self._mesh(tuple(plan.grid), _AXES_2D)
         return sm.plan(scheme=plan, mesh=mesh, impl=impl, block=self.block,
-                       hw=self.hw)
+                       hw=self.hw, semiring=semiring)
 
     def _build(self, sm: SparseMatrix, plan: Plan, key: PlanKey,
                impl: str, part=None, assignment=None, trace=NULL_TRACE,
-               scope: str = "spmv.compile") -> CompiledPlan:
+               scope: str = "spmv.compile",
+               semiring: str = "plus_times") -> CompiledPlan:
         """Run the api chain once for ``plan`` and wrap the MeshExecutor.
 
         ``part`` short-circuits host partitioning with a spilled
@@ -210,7 +212,7 @@ class SpmvEngine:
         may still run after it) are recorded on ``trace``.
         """
         with phase(f"{scope}.partition", trace) as part_phase:
-            ep = self._plan(sm, plan, impl, assignment)
+            ep = self._plan(sm, plan, impl, assignment, semiring)
             if part is not None:
                 ep.part = part  # spilled host partition: skip re-partitioning
             else:
@@ -247,6 +249,7 @@ class SpmvEngine:
         partitioning: Optional[str] = None,
         warmup: bool = True,
         impl: Optional[str] = None,
+        semiring: str = "plus_times",
     ) -> RegisteredMatrix:
         """Fingerprint, plan, partition, place and compile ``a`` under ``name``.
 
@@ -269,6 +272,13 @@ class SpmvEngine:
             the engine-wide ``self.impl``.  Pallas plans carry their chunk
             plans in the cached placement, so the micro-batched SpMM path
             runs the lane-tiled Pallas kernels end to end.
+          semiring: ``"plus_times"`` (y = A x) or ``"min_plus"``
+            (y_i = min_j A_ij + x_j: shortest-path relaxation, with
+            ``solve(combine="sssp")``), fixed for the registration.  It
+            enters the fingerprint, so the plan-cache key.  A ``min_plus``
+            matrix comes from triplets (``a=None`` over a registry entry
+            holding a ``SparseMatrix.from_parts`` matrix); its stored zeros
+            are edges, and it is never densified.
 
         Returns:
           The RegisteredMatrix registry entry; its ``register_s`` holds the
@@ -276,27 +286,34 @@ class SpmvEngine:
           annotations: stats, fingerprint, plan, partition, place, compile).
 
         Raises:
-          ValueError: for a non-2D matrix, an unknown ``impl``, or ``a=None``
-            without a prior registration holding the host-side matrix.
+          ValueError: for a non-2D matrix, an unknown ``impl``, ``a=None``
+            without a prior registration holding the host-side matrix, a
+            dense ``a`` with ``semiring="min_plus"``, or a plan that cannot
+            run the semiring (:func:`repro.api.plan.check_semiring`).
         """
         seconds = _PhaseSeconds()
         with phase("spmv.register", matrix=name):
             entry = self._register(name, a, dtype, plan, partitioning, warmup,
-                                   impl, seconds)
+                                   impl, semiring, seconds)
         entry.register_s = dict(seconds)
         return entry
 
     def _register(self, name, a, dtype, plan, partitioning, warmup, impl,
-                  seconds: _PhaseSeconds) -> RegisteredMatrix:
+                  semiring, seconds: _PhaseSeconds) -> RegisteredMatrix:
+        if semiring != "plus_times" and a is not None:
+            raise ValueError(
+                f"semiring {semiring!r} needs the matrix as triplets: a dense "
+                "array cannot say which zeros are edges; stage a "
+                "SparseMatrix.from_parts matrix and register(name, None)")
         prior = self.registry.find(name)
         with phase("spmv.register.stats", seconds):
-            sm = self._host_matrix(name, a, dtype, prior)
+            sm = self._host_matrix(name, a, dtype, prior, semiring)
             impl = self.impl if impl is None else impl
             if impl not in ("xla", "pallas"):
                 raise ValueError(f"unknown impl {impl!r}: 'xla' or 'pallas'")
             stats = sm.stats
         with phase("spmv.register.fingerprint", seconds):
-            fp = sm.fingerprint()
+            fp = sm.fingerprint(semiring)
         with phase("spmv.register.plan", seconds):
             plan = resolve_scheme(
                 stats, sm.shape, self.n_devices,
@@ -304,6 +321,7 @@ class SpmvEngine:
                 hw=self.hw, partitioning=partitioning, block=self.block,
                 compiled_pallas=(impl == "pallas"
                                  and not interpret_mode(self.devices)),
+                semiring=semiring,
             )
             key: PlanKey = (fp, tuple(plan.grid), sm.dtype.str, plan.tag,
                             impl)
@@ -315,7 +333,8 @@ class SpmvEngine:
             part = (prior.spill
                     if prior is not None and prior.cache_key == key else None)
             compiled = self._build(sm, plan, key, impl, part=part,
-                                   trace=seconds, scope="spmv.register")
+                                   trace=seconds, scope="spmv.register",
+                                   semiring=semiring)
             with self._swap_lock:
                 self.cache.put(compiled)
         entry = RegisteredMatrix(
@@ -328,6 +347,7 @@ class SpmvEngine:
             cache_key=key,
             matrix=sm,  # host-side; lets the tuner + reactivation re-plan
             row_slices=compiled.executor.plan.row_slices,
+            semiring=semiring,
         )
         # overwriting a name must not strand the old plan in the cache
         self.registry.add(entry)
@@ -341,7 +361,8 @@ class SpmvEngine:
                 compiled.executor.warmup()
         return entry
 
-    def _host_matrix(self, name, a, dtype, prior) -> SparseMatrix:
+    def _host_matrix(self, name, a, dtype, prior,
+                     semiring: str = "plus_times") -> SparseMatrix:
         """The host-side SparseMatrix to register: built from ``a``, or the
         one a prior registration of ``name`` holds."""
         if a is None:
@@ -352,6 +373,9 @@ class SpmvEngine:
                 )
             sm = prior.matrix
             if dtype is not None and np.dtype(dtype) != sm.dtype:
+                if semiring != "plus_times":  # never densified
+                    return SparseMatrix.from_parts(
+                        *sm.entries(), sm.shape, dtype=dtype)
                 sm = SparseMatrix.from_dense(
                     sm.dense().astype(dtype), stats_block=self.block
                 )
@@ -408,7 +432,8 @@ class SpmvEngine:
             )
         with phase("spmv.compile", matrix=name):
             built = self._build(entry.matrix, entry.plan, entry.cache_key,
-                                entry.cache_key[4], part=entry.spill)
+                                entry.cache_key[4], part=entry.spill,
+                                semiring=entry.semiring)
             with self._swap_lock:
                 if self.cache.peek(entry.cache_key) is not None:
                     built.release()  # lost a race; the cached build wins
@@ -663,8 +688,13 @@ class SpmvEngine:
           KeyError: unknown ``name``.
           RuntimeError: the entry was registered by a pre-tune engine and
             carries no matrix to re-plan from.
+          ValueError: the entry runs another semiring than (+, x); the tuner
+            measures (+, x) candidates only.
         """
         entry = self.registry.get(name)
+        if entry.semiring != "plus_times":
+            raise ValueError(f"refine measures (+, x) plans only; {name!r} "
+                             f"is registered {entry.semiring}")
         if entry.matrix is None:
             raise RuntimeError(
                 f"{name!r} has no host-side SparseMatrix to tune from"

@@ -50,6 +50,8 @@ class RegisteredMatrix:
     row_slices: Optional[dict] = None  # the XLA scalar tile kernel's
     # layout: slice width, padded share and partials merged per SpMV
     # (ExecutionPlan.row_slices; None for other kernels)
+    semiring: str = "plus_times"  # fixed at registration ("min_plus": the
+    # shortest-path relaxation); part of the fingerprint
 
     def summary(self) -> dict:
         """JSON-safe identity + serving state — what crosses a process

@@ -28,11 +28,13 @@ __all__ = ["spmv", "spmm", "pallas_program", "spmv_local_coo",
            "spmv_local_block", "ell_spmv_pallas", "kernel_scope"]
 
 
-def kernel_scope(fmt: str, impl: str):
+def kernel_scope(fmt: str, impl: str, semiring: str = "plus_times"):
     """``jax.named_scope("spmv.<fmt>.<impl>")``, ``spmv.coo.xla``: the name a
     device profile gives a local SpMV kernel's ops (metadata only; the
-    compiled program does not change)."""
-    return jax.named_scope(f"spmv.{fmt}.{impl}")
+    compiled program does not change).  A kernel of another semiring than
+    (+, x) gets its name as a last part: ``spmv.coo.xla.min_plus``."""
+    suffix = "" if semiring == "plus_times" else f".{semiring}"
+    return jax.named_scope(f"spmv.{fmt}.{impl}{suffix}")
 
 
 def _require_concrete(m) -> None:

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 __all__ = [
     "coo_spmv_ref",
+    "coo_min_plus_ref",
     "csr_spmv_ref",
     "bcsr_spmv_ref",
     "bcoo_spmv_ref",
@@ -61,6 +62,26 @@ def coo_spmv_ref(
     prod = jnp.where(valid[(...,) + (None,) * (x.ndim - 1)], prod, 0)
     y = jnp.zeros((out_rows,) + x.shape[1:], acc)
     y = y.at[rowind].add(prod, mode="drop")
+    return y.astype(values.dtype) if values.dtype != acc else y
+
+
+def coo_min_plus_ref(
+    rowind: jax.Array,
+    colind: jax.Array,
+    values: jax.Array,
+    x: jax.Array,
+    out_rows: int,
+) -> jax.Array:
+    """(min, +) product over COO triplets: y[r] = min_k values[k] + x[colind[k]]
+    for rowind[k] == r, +inf for a row with no entry.
+
+    The plain reference of the semiring the row-slice kernel runs
+    (``row_slice_spmv(..., semiring="min_plus")``): every triplet is an
+    entry (a stored 0 is an edge of weight 0), no slices, no mesh.
+    """
+    acc = _acc_dtype(values.dtype)
+    cand = values.astype(acc) + jnp.take(x, colind, axis=0).astype(acc)
+    y = jnp.full((out_rows,), jnp.inf, acc).at[rowind].min(cand)
     return y.astype(values.dtype) if values.dtype != acc else y
 
 

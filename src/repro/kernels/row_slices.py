@@ -16,6 +16,12 @@ is ``w`` lane-dense vector adds.  Two modes, chosen from the rows alone:
 
 ``choose_width`` picks ``w`` from the row-length histogram of the whole
 partition (docs/kernels.md, "COO / CSR on the XLA path: row slices").
+
+The layout and the kernel take a *semiring* (:mod:`repro.core.semiring`):
+``"plus_times"`` (y = A x) or ``"min_plus"`` (y_i = min_j (A_ij + x_j), the
+relaxation of shortest paths).  Padding holds the semiring's zero: value 0
+under (+, x), +inf under (min, +), so a pad lane, a padding slice or a row
+with no slice reads that zero.
 """
 from __future__ import annotations
 
@@ -25,6 +31,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.core.semiring import lookup
 
 from .ref import _acc_dtype
 
@@ -48,7 +56,6 @@ ONE_SLICE_MAX = 64
 # matrices are the fastest measured there, and stay the picks for any value
 # from 0.6 to 2.8 (PERF.md, "Row slices: measure first").
 MERGE_COST = 1.3
-
 
 @dataclass(frozen=True)
 class SliceLayout:
@@ -122,7 +129,7 @@ def choose_width(row_lengths: Sequence[np.ndarray]) -> tuple[int, bool]:
 
 def build_row_slices(rowind: np.ndarray, colind: np.ndarray,
                      values: np.ndarray, nnz: np.ndarray,
-                     h_pad: int) -> SliceLayout:
+                     h_pad: int, semiring: str = "plus_times") -> SliceLayout:
     """Lay a partition's entries out as row slices (host side, O(nnz)).
 
     Args:
@@ -130,10 +137,11 @@ def build_row_slices(rowind: np.ndarray, colind: np.ndarray,
         values, the first ``nnz[p]`` entries of part p real.
       nnz: (P,) real entries a part.
       h_pad: the padded tile height (rows of y a part).
+      semiring: whose zero pads lanes and slices.
 
     Returns:
       The :class:`SliceLayout`.  Parts are padded to one ``S`` with
-      zero-value slices on the last tile row, so ``slice_row`` stays sorted.
+      padding slices on the last tile row, so ``slice_row`` stays sorted.
     """
     parts = rowind.shape[0]
     entries = []
@@ -146,7 +154,7 @@ def build_row_slices(rowind: np.ndarray, colind: np.ndarray,
     w, one_slice = choose_width(lengths)
     S = h_pad if one_slice else max(1, _slice_counts(lengths, w))
     ci = np.zeros((parts, w, S), np.int32)
-    vv = np.zeros((parts, w, S), values.dtype)
+    vv = np.full((parts, w, S), lookup(semiring).zero, values.dtype)
     srow = np.full((parts, S), h_pad - 1, np.int32)
     for p, ((r, c, v), lens) in enumerate(zip(entries, lengths)):
         pos = np.arange(len(r)) - (np.cumsum(lens) - lens)[r]
@@ -167,24 +175,31 @@ def build_row_slices(rowind: np.ndarray, colind: np.ndarray,
 
 
 def row_slice_spmv(colind: jax.Array, values: jax.Array, x: jax.Array,
-                   out_rows: int, row: Optional[jax.Array] = None) -> jax.Array:
-    """y = A x (or A X) for one tile in row slices.
+                   out_rows: int, row: Optional[jax.Array] = None,
+                   semiring: str = "plus_times") -> jax.Array:
+    """y = A x (or A X) for one tile in row slices, in ``semiring``.
 
     Args:
-      colind, values: (w, S) slice entries (padding: value 0, column 0).
+      colind, values: (w, S) slice entries (padding: the semiring's zero,
+        column 0).
       x: (n,) vector or (n, B) batch.
       out_rows: rows of y (the tile height).
       row: (S,) sorted tile row of each slice; None when every row is one
         slice (then ``S == out_rows``).
+      semiring: ``"plus_times"`` (products summed) or ``"min_plus"``
+        (``values + x[col]``, the least taken; a row with no slice reads
+        +inf).  Static.
 
     Returns:
       y (out_rows[, B]) in the values dtype, accumulated in
-      ``_acc_dtype(values.dtype)``, as :func:`~repro.kernels.ref.coo_spmv_ref`.
+      ``_acc_dtype(values.dtype)``, as :func:`~repro.kernels.ref.coo_spmv_ref`
+      (or :func:`~repro.kernels.ref.coo_min_plus_ref`).
     """
     acc = _acc_dtype(values.dtype)
+    sr = lookup(semiring)
     xv = jnp.take(x, colind, axis=0, mode="clip").astype(acc)  # (w, S[, B])
-    prod = values.astype(acc)[(...,) + (None,) * (x.ndim - 1)] * xv
-    partial = prod.sum(axis=0)  # (S[, B])
-    y = partial if row is None else jax.ops.segment_sum(
+    v = values.astype(acc)[(...,) + (None,) * (x.ndim - 1)]
+    partial = sr.reduce(sr.times(v, xv), axis=0)  # (S[, B])
+    y = partial if row is None else sr.segment(
         partial, row, num_segments=out_rows, indices_are_sorted=True)
     return y.astype(values.dtype) if values.dtype != acc else y
